@@ -126,7 +126,9 @@ def collective_audit(cfg, devices: int = 8) -> Dict[str, Dict[str, int]]:
 
     import dataclasses
 
-    env = dict(os.environ)
+    # the count is a static HLO walk on forced host devices: the child must
+    # never reach for an accelerator this process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices} "
         + env.get("XLA_FLAGS", "")).strip()

@@ -33,7 +33,7 @@ import dataclasses
 from typing import FrozenSet, List, Sequence, Tuple
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 from jax.tree_util import tree_flatten_with_path
 
 from repro.analysis.jaxpr_walk import aval_key, iter_eqns

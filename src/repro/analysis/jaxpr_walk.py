@@ -11,7 +11,7 @@ from __future__ import annotations
 
 from typing import Iterator, List, Tuple
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 def _sub_jaxprs(value) -> Iterator[jcore.Jaxpr]:

@@ -30,6 +30,8 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.sharding.vma import match_vma
+
 
 @jax.custom_vjp
 def _gates_lowp(wx, wh, b, x, h):
@@ -105,7 +107,8 @@ def lstm_cell(params, x, h_prev, c_prev, *, use_pallas: bool = False):
                  + jnp.dot(h_prev, params["wh"], preferred_element_type=jnp.float32)
                  + params["b"].astype(jnp.float32)).astype(x.dtype)
     else:
-        gates = _gates_lowp(params["wx"], params["wh"], params["b"], x, h_prev)
+        gates = _gates_lowp(*match_vma(params["wx"], params["wh"], params["b"],
+                                       x, h_prev))
     i, f, g, o = jnp.split(gates, 4, axis=-1)
     c = (jax.nn.sigmoid(f) * c_prev.astype(x.dtype)
          + jax.nn.sigmoid(i) * jnp.tanh(g))
@@ -166,8 +169,10 @@ def _dilated_layer(cell, xs, d: int, *, use_pallas: bool):
               .reshape(b * d, tp // d, f))
         bd = b * d
 
-    h0 = jnp.zeros((bd, hidden), xs.dtype)
-    c0 = jnp.zeros((bd, hidden), xs.dtype)
+    # zero carries derived from the data, so that inside shard_map they vary
+    # over the same mesh axes as the per-step outputs
+    h0 = jnp.broadcast_to(jnp.zeros_like(xr[:, :1, 0]), (bd, hidden))
+    c0 = h0
 
     def step(carry, x_t):
         h, c = carry

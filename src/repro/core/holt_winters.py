@@ -167,11 +167,11 @@ def hw_smooth(
       seasonality: period ``m`` (1 => non-seasonal; seasonality fixed at 1.0).
       seasonality2: optional second period (0 => disabled).
       use_pallas: route the recurrence through the Pallas TPU kernel
-        (``kernels/hw_scan.py``); only the single-seasonality path has a
-        kernel. Numerics are identical (kernel is tested against this path)
-        and the kernel is differentiable -- its custom_vjp runs the adjoint
-        recurrence time-reversed as a second kernel, so training with
-        ``use_pallas=True`` works end-to-end.
+        (``kernels/hw_scan.py``). Numerics are identical (kernel is tested
+        against this path) and the kernel is differentiable -- its
+        custom_vjp runs the adjoint recurrence time-reversed as a second
+        kernel, so training with ``use_pallas=True`` works end-to-end. The
+        kernel has one seasonality ring: with ``seasonality2`` it raises.
 
     Returns:
       levels: ``(N, T)`` level l_t after observing y_t.
@@ -180,7 +180,12 @@ def hw_smooth(
         ``T .. T+m-1`` are the smoothed future factors. For ``seasonality2``
         the product of both rings is returned (what de-seasonalization uses).
     """
-    if use_pallas and seasonality2 == 0:
+    if use_pallas:
+        if seasonality2:
+            raise NotImplementedError(
+                "use_pallas=True has no dual-seasonality Holt-Winters kernel: "
+                "kernels/hw_scan.py runs one seasonality ring, and this "
+                f"config sets seasonality2={seasonality2}")
         from repro.kernels import ops as kernel_ops
 
         return kernel_ops.hw_scan(y, params, seasonality=seasonality)

@@ -27,7 +27,6 @@ data), the math stays functional and jitted.
 from __future__ import annotations
 
 import json
-import logging
 import os
 from typing import Dict, Optional, Sequence, Tuple, Union
 
@@ -46,8 +45,6 @@ from repro.data.pipeline import PreparedData, chunk_bounds, prepare
 from repro.data.synthetic_m4 import M4Dataset, generate
 from repro.forecast.spec import ForecastSpec, get_spec
 from repro.train.trainer import train_from_spec
-
-log = logging.getLogger("repro.forecast")
 
 _META_FILE = "forecaster.json"
 
@@ -195,25 +192,16 @@ class ESRNNForecaster:
 
         Mirrors ``fit``'s resolution rule so an estimator fitted with
         ``data_parallel=8`` serves predict/evaluate/backtest sharded the
-        same way without re-plumbing a mesh through every call. A 1-device
-        mesh degenerates to the single-device path (identical math, no
-        shard_map hop).
+        same way without re-plumbing a mesh through every call. Like
+        ``fit``, it raises when fewer devices are present than the spec
+        asks for (on a smaller host, lower ``spec.data_parallel`` or pass a
+        mesh that fits). A 1-device mesh degenerates to the single-device path
+        (identical math, no shard_map hop).
         """
         if mesh is None and self.spec.data_parallel > 1:
             from repro.sharding.series import make_series_mesh
 
-            try:
-                mesh = make_series_mesh(self.spec.data_parallel)
-            except ValueError:
-                # an estimator fitted data-parallel elsewhere must still
-                # predict on a smaller host: inference is semantically
-                # identical on any device count, so degrade to single-device
-                # (training keeps raising -- its mesh is an explicit ask)
-                log.warning(
-                    "spec.data_parallel=%d exceeds the %d available "
-                    "device(s); inference runs single-device",
-                    self.spec.data_parallel, len(jax.devices()))
-                mesh = None
+            mesh = make_series_mesh(self.spec.data_parallel)
         if mesh is not None and mesh.devices.size == 1:
             mesh = None
         return mesh

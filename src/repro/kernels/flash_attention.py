@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BQ = 256
 DEFAULT_BK = 256
@@ -116,19 +117,11 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, bq, d), q_map),
         out_shape=jax.ShapeDtypeStruct((b * hq, tq, d), q.dtype),
         scratch_shapes=[
-            _vmem((bq, 1), jnp.float32),
-            _vmem((bq, 1), jnp.float32),
-            _vmem((bq, d), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(b, hq, tq, d)
 
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover
-        return pl.MemorySpace.ANY(shape, dtype)  # type: ignore[attr-defined]

@@ -48,42 +48,49 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.sharding import vma
 
 # Series-per-block: one full lane row. Sublane dim is time (streamed).
 BLOCK_N = 128
 
 
+def _row(ref, t):
+    """Row ``t`` of a ``(rows, BN)`` ref as a ``(1, BN)`` tile.
+
+    Every value in both kernels is a 2-D ``(1, BN)`` row: Mosaic lays out
+    vectors of rank 2 and up, and a dynamic single-row access at ``t`` is
+    a sublane-offset load or store on an fp32 tile.
+    """
+    return ref[pl.ds(t, 1), :]
+
+
 def _hw_scan_kernel(y_ref, a_ref, g_ref, s0_ref, lev_ref, seas_ref, ring_ref,
                     *, t_len: int, m: int):
-    alpha = a_ref[0, :]                     # (BN,)
-    gamma = g_ref[0, :]
-    # Precision policy: y may stream in bf16 (half-width VMEM tiles), but the
-    # level/seasonality recurrence accumulates in the param dtype (fp32) --
-    # each loaded y row is widened before use, state never rounds down.
-    state_dt = alpha.dtype
+    alpha = a_ref[...]                      # (1, BN)
+    gamma = g_ref[...]
 
     # init the seasonality ring in VMEM scratch
     ring_ref[...] = s0_ref[...]
 
     def body(t, l_prev):
         slot = jax.lax.rem(t, m)
-        y_t = pl.load(y_ref, (pl.ds(t, 1), slice(None)))[0].astype(state_dt)
-        s_t = pl.load(ring_ref, (pl.ds(slot, 1), slice(None)))[0]
+        y_t = _row(y_ref, t)
+        s_t = _row(ring_ref, slot)
         l_t = alpha * y_t / s_t + (1.0 - alpha) * l_prev
         s_new = gamma * y_t / l_t + (1.0 - gamma) * s_t
-        pl.store(ring_ref, (pl.ds(slot, 1), slice(None)), s_new[None, :])
-        pl.store(lev_ref, (pl.ds(t, 1), slice(None)), l_t[None, :])
-        pl.store(seas_ref, (pl.ds(t, 1), slice(None)), s_t[None, :])
+        ring_ref[pl.ds(slot, 1), :] = s_new
+        lev_ref[pl.ds(t, 1), :] = l_t
+        seas_ref[pl.ds(t, 1), :] = s_t
         return l_t
 
-    l0 = y_ref[0, :].astype(state_dt) / s0_ref[0, :]
+    l0 = _row(y_ref, 0) / _row(s0_ref, 0)
     jax.lax.fori_loop(0, t_len, body, l0)
 
     # trailing future factors s_T .. s_{T+M-1} live in ring slots (T+k) mod M
     for k in range(m):  # m is static and small (<= 24)
-        slot = (t_len + k) % m
-        row = pl.load(ring_ref, (pl.ds(slot, 1), slice(None)))
-        pl.store(seas_ref, (pl.ds(t_len + k, 1), slice(None)), row)
+        seas_ref[pl.ds(t_len + k, 1), :] = _row(ring_ref, (t_len + k) % m)
 
 
 def _hw_scan_bwd_kernel(y_ref, a_ref, g_ref, lev_ref, seas_ref,
@@ -96,20 +103,17 @@ def _hw_scan_bwd_kernel(y_ref, a_ref, g_ref, lev_ref, seas_ref,
     step t, slot ``t mod m`` holds ``sig_{t+m}`` (the fully-accumulated
     cotangent of s_{t+m}); the step overwrites it with ``sig_t``.
     """
-    alpha = a_ref[0, :]                     # (BN,)
-    gamma = g_ref[0, :]
-    state_dt = alpha.dtype
+    alpha = a_ref[...]                      # (1, BN)
+    gamma = g_ref[...]
     # s_0 == init_seas_0: the forward emits it as seas row 0, so the
     # init_seas array itself need not be streamed into the backward.
-    s00 = seas_ref[0, :]
-    y0 = y_ref[0, :].astype(state_dt)
+    s00 = _row(seas_ref, 0)
+    y0 = _row(y_ref, 0)
 
     # seed: the trailing future factors s_T .. s_{T+M-1} are pure outputs,
     # so their cotangents are exactly the incoming dseas rows.
     for k in range(m):
-        slot = (t_len + k) % m
-        row = pl.load(dseas_ref, (pl.ds(t_len + k, 1), slice(None)))
-        pl.store(ring_ref, (pl.ds(slot, 1), slice(None)), row)
+        ring_ref[pl.ds((t_len + k) % m, 1), :] = _row(dseas_ref, t_len + k)
 
     zeros = jnp.zeros_like(alpha)
 
@@ -117,28 +121,26 @@ def _hw_scan_bwd_kernel(y_ref, a_ref, g_ref, lev_ref, seas_ref,
         lam_next, da, dg = carry
         t = t_len - 1 - i
         slot = jax.lax.rem(t, m)
-        y_t = pl.load(y_ref, (pl.ds(t, 1), slice(None)))[0].astype(state_dt)
-        l_t = pl.load(lev_ref, (pl.ds(t, 1), slice(None)))[0]
-        s_t = pl.load(seas_ref, (pl.ds(t, 1), slice(None)))[0]
+        y_t = _row(y_ref, t)
+        l_t = _row(lev_ref, t)
+        s_t = _row(seas_ref, t)
         # l_{t-1}: levels row t-1 for t > 0, else the primer l_{-1} = y_0/s_0
-        l_prev = pl.load(lev_ref, (pl.ds(jnp.maximum(t - 1, 0), 1),
-                                   slice(None)))[0]
-        l_prev = jnp.where(t > 0, l_prev, y0 / s00)
-        sig_tpm = pl.load(ring_ref, (pl.ds(slot, 1), slice(None)))[0]
+        l_prev = jnp.where(t > 0, _row(lev_ref, jnp.maximum(t - 1, 0)),
+                           y0 / s00)
+        sig_tpm = _row(ring_ref, slot)
 
-        lam_t = (pl.load(dlev_ref, (pl.ds(t, 1), slice(None)))[0]
+        lam_t = (_row(dlev_ref, t)
                  + (1.0 - alpha) * lam_next
                  - sig_tpm * gamma * y_t / (l_t * l_t))
-        sig_t = (pl.load(dseas_ref, (pl.ds(t, 1), slice(None)))[0]
+        sig_t = (_row(dseas_ref, t)
                  + (1.0 - gamma) * sig_tpm
                  - lam_t * alpha * y_t / (s_t * s_t))
-        pl.store(ring_ref, (pl.ds(slot, 1), slice(None)), sig_t[None, :])
+        ring_ref[pl.ds(slot, 1), :] = sig_t
 
         dy_t = lam_t * alpha / s_t + sig_tpm * gamma / l_t
         # l_{-1} = y_0 / s_0 adds (1-alpha)*lam_0 / s_0 to dy_0
         dy_t = dy_t + jnp.where(t == 0, (1.0 - alpha) * lam_t / s00, 0.0)
-        pl.store(dy_ref, (pl.ds(t, 1), slice(None)),
-                 dy_t.astype(dy_ref.dtype)[None, :])
+        dy_ref[pl.ds(t, 1), :] = dy_t
 
         da = da + lam_t * (y_t / s_t - l_prev)
         dg = dg + sig_tpm * (y_t / l_t - s_t)
@@ -146,24 +148,23 @@ def _hw_scan_bwd_kernel(y_ref, a_ref, g_ref, lev_ref, seas_ref,
 
     lam0, da, dg = jax.lax.fori_loop(0, t_len, body, (zeros, zeros, zeros))
 
-    da_ref[...] = da[None, :]
-    dg_ref[...] = dg[None, :]
+    da_ref[...] = da
+    dg_ref[...] = dg
     # after the loop, ring slot k holds sig_k == d loss / d init_seas_k
     ds0_ref[...] = ring_ref[...]
     # ... minus the primer-level term through l_{-1} = y_0 / s_0 on slot 0
     corr = (1.0 - alpha) * lam0 * y0 / (s00 * s00)
-    row0 = pl.load(ds0_ref, (pl.ds(0, 1), slice(None)))[0]
-    pl.store(ds0_ref, (pl.ds(0, 1), slice(None)), (row0 - corr)[None, :])
+    ds0_ref[pl.ds(0, 1), :] = _row(ds0_ref, 0) - corr
 
 
 def _hw_scan_fwd_call(y_tm, alpha, gamma, init_seas_tm, *, interpret: bool):
     t_len, n = y_tm.shape
     m = init_seas_tm.shape[0]
-    # outputs and the VMEM ring carry the *param* (state) dtype: under the
-    # bf16 policy only the streamed y tiles are half width, the recurrence
-    # state stays fp32
+    # y arrives already widened to the param (state) dtype, which every
+    # output and the VMEM ring carry (see hw_scan_tm)
     dtype = alpha.dtype
     grid = (n // BLOCK_N,)
+    out = vma.out_shape(y_tm, alpha, gamma, init_seas_tm)
 
     kernel = functools.partial(_hw_scan_kernel, t_len=t_len, m=m)
     levels, seas = pl.pallas_call(
@@ -180,10 +181,10 @@ def _hw_scan_fwd_call(y_tm, alpha, gamma, init_seas_tm, *, interpret: bool):
             pl.BlockSpec((t_len + m, BLOCK_N), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((t_len, n), dtype),
-            jax.ShapeDtypeStruct((t_len + m, n), dtype),
+            out((t_len, n), dtype),
+            out((t_len + m, n), dtype),
         ],
-        scratch_shapes=[_vmem_scratch((m, BLOCK_N), dtype)],
+        scratch_shapes=[pltpu.VMEM((m, BLOCK_N), dtype)],
         interpret=interpret,
     )(y_tm, alpha[None, :], gamma[None, :], init_seas_tm)
     return levels, seas
@@ -192,10 +193,9 @@ def _hw_scan_fwd_call(y_tm, alpha, gamma, init_seas_tm, *, interpret: bool):
 def _hw_scan_bwd_call(y_tm, alpha, gamma, levels, seas, dlev, dseas, *,
                       m: int, interpret: bool):
     t_len, n = y_tm.shape
-    # param/init-seas cotangents accumulate in the state dtype; only dy
-    # drops back to the (possibly bf16) observation dtype
     dtype = alpha.dtype
     grid = (n // BLOCK_N,)
+    out = vma.out_shape(y_tm, alpha, gamma, levels, seas, dlev, dseas)
 
     kernel = functools.partial(_hw_scan_bwd_kernel, t_len=t_len, m=m)
     col = lambda rows: pl.BlockSpec((rows, BLOCK_N), lambda i: (0, i))
@@ -213,12 +213,12 @@ def _hw_scan_bwd_call(y_tm, alpha, gamma, levels, seas, dlev, dseas, *,
         ],
         out_specs=[col(t_len), col(1), col(1), col(m)],
         out_shape=[
-            jax.ShapeDtypeStruct((t_len, n), y_tm.dtype),
-            jax.ShapeDtypeStruct((1, n), dtype),
-            jax.ShapeDtypeStruct((1, n), dtype),
-            jax.ShapeDtypeStruct((m, n), dtype),
+            out((t_len, n), dtype),
+            out((1, n), dtype),
+            out((1, n), dtype),
+            out((m, n), dtype),
         ],
-        scratch_shapes=[_vmem_scratch((m, BLOCK_N), dtype)],
+        scratch_shapes=[pltpu.VMEM((m, BLOCK_N), dtype)],
         interpret=interpret,
     )(y_tm, alpha[None, :], gamma[None, :], levels, seas, dlev, dseas)
     return dy, da[0], dg[0], ds0
@@ -258,17 +258,12 @@ def hw_scan_tm(y_tm, alpha, gamma, init_seas_tm, *, interpret: bool = False):
     N must be a multiple of BLOCK_N (ops.py pads). Returns levels_tm (T, N)
     and seas_tm (T+M, N). Differentiable: carries a custom_vjp whose backward
     is the time-reversed adjoint kernel (see module docstring).
+
+    Precision policy: y may be bf16, but the level/seasonality recurrence
+    runs in the param dtype (fp32). y is widened here, before the kernel,
+    so the kernel only ever reads and writes rows of the state dtype: a
+    dynamic single-row access into a packed bf16 tile is not a layout the
+    TPU compiler accepts. The widening's transpose returns dy in y's dtype.
     """
+    y_tm = y_tm.astype(alpha.dtype)
     return _hw_scan_tm(interpret, y_tm, alpha, gamma, init_seas_tm)
-
-
-def _vmem_scratch(shape, dtype):
-    """VMEM scratch allocation, tolerant of pallas API surface differences."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # CPU-only interpret environments without the TPU ext
-        # pl.MemorySpace.ANY is an enum member, not a constructor; wrap it in
-        # a MemoryRef the way pltpu.VMEM does (see test_hw_scan fallback test)
-        return pl.MemoryRef(shape, jnp.dtype(dtype), pl.MemorySpace.ANY)

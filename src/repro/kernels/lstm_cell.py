@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.sharding import vma
+
 BLOCK_B = 128
 
 
@@ -151,6 +153,7 @@ def _lstm_fwd_call(wx, wh, b, x, h, c, *, interpret: bool, with_acts: bool,
     dtype = x.dtype
     grid = (bsz // block_b,)
     full, tile = _lstm_call_specs(block_b)
+    out = vma.out_shape(wx, wh, b, x, h, c)
     in_specs = [
         full(input_size, 4 * hidden),
         full(hidden, 4 * hidden),
@@ -161,13 +164,13 @@ def _lstm_fwd_call(wx, wh, b, x, h, c, *, interpret: bool, with_acts: bool,
     ]
     out_specs = [tile(hidden), tile(hidden)]
     out_shape = [
-        jax.ShapeDtypeStruct((bsz, hidden), dtype),
-        jax.ShapeDtypeStruct((bsz, hidden), dtype),
+        out((bsz, hidden), dtype),
+        out((bsz, hidden), dtype),
     ]
     if with_acts:
         kernel = functools.partial(_lstm_fwd_kernel, hidden=hidden)
         out_specs = out_specs + [tile(4 * hidden)]
-        out_shape = out_shape + [jax.ShapeDtypeStruct((bsz, 4 * hidden), dtype)]
+        out_shape = out_shape + [out((bsz, 4 * hidden), dtype)]
     else:
         kernel = functools.partial(_lstm_kernel, hidden=hidden)
     return pl.pallas_call(
@@ -183,6 +186,7 @@ def _lstm_bwd_call(wx, wh, x, h, c, c_new, act, dh, dc, *, interpret: bool,
     dtype = x.dtype
     grid = (bsz // block_b,)
     full, tile = _lstm_call_specs(block_b)
+    out = vma.out_shape(wx, wh, x, h, c, c_new, act, dh, dc)
     kernel = functools.partial(_lstm_bwd_kernel, hidden=hidden)
     dx, dhp, dcp, dwx, dwh, db = pl.pallas_call(
         kernel,
@@ -207,17 +211,17 @@ def _lstm_bwd_call(wx, wh, x, h, c, c_new, act, dh, dc, *, interpret: bool,
             full(1, 4 * hidden),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, input_size), dtype),
-            jax.ShapeDtypeStruct((bsz, hidden), dtype),
-            jax.ShapeDtypeStruct((bsz, hidden), dtype),
+            out((bsz, input_size), dtype),
+            out((bsz, hidden), dtype),
+            out((bsz, hidden), dtype),
             # weight/bias grads accumulate across the sequential batch-grid
             # steps: always fp32, or a bf16 stream would round the running
             # sum at every revisit (the bf16-policy failure mode this
             # kernel exists to avoid). Cast back to the param dtype happens
             # in the vjp wrapper, after the sum is complete.
-            jax.ShapeDtypeStruct((input_size, 4 * hidden), jnp.float32),
-            jax.ShapeDtypeStruct((hidden, 4 * hidden), jnp.float32),
-            jax.ShapeDtypeStruct((1, 4 * hidden), jnp.float32),
+            out((input_size, 4 * hidden), jnp.float32),
+            out((hidden, 4 * hidden), jnp.float32),
+            out((1, 4 * hidden), jnp.float32),
         ],
         interpret=interpret,
     )(wx, wh, x, h, c, c_new, act, dh, dc)
@@ -261,4 +265,5 @@ def lstm_cell_padded(wx, wh, b, x, h, c, *, interpret: bool = False,
     gradient kernel (see module docstring). ``block_b`` is the batch tile
     per grid step (:func:`block_b_for` picks it from the stream dtype).
     """
-    return _lstm_cell_padded(interpret, block_b, wx, wh, b, x, h, c)
+    return _lstm_cell_padded(interpret, block_b,
+                             *vma.match_vma(wx, wh, b, x, h, c))

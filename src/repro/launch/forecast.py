@@ -68,6 +68,7 @@ from __future__ import annotations
 
 import argparse
 import logging
+import os
 
 import numpy as np
 
@@ -77,6 +78,26 @@ from repro.forecast import (
 )
 
 log = logging.getLogger("repro.launch.forecast")
+
+# fixed default: the cache directory is part of every entry's key, so a
+# path that moved between runs would never hit
+_REPO_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins when set, else ``<repo>/.jax_cache``.
+    Call before the first compile: JAX decides once per process whether the
+    cache is in use.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or _REPO_CACHE
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _parse_overrides(pairs):
@@ -459,6 +480,7 @@ def main(argv=None):
 
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    use_compile_cache()
     return args.fn(args)
 
 

@@ -131,7 +131,9 @@ def ssd_chunked(x, dt, a, bb, cc, *, chunk: int):
         s_new = s_prev * dec[:, :, None, None] + st
         return s_new, s_prev                            # emit state *entering* chunk
 
-    s0 = jnp.zeros((b, h, p, n), jnp.float32)
+    # derived from the data, so that inside shard_map the carry varies over
+    # the same mesh axes as the per-chunk states
+    s0 = jnp.zeros_like(states[:, 0])
     s_final, s_in = jax.lax.scan(
         step,
         s0,

@@ -10,10 +10,10 @@ replicated and their gradients all-reduced (the transpose of replication
 under ``shard_map`` autodiff is exactly the psum the data-parallel update
 needs).
 
-Built on the current JAX API only: :func:`jax.make_mesh`,
-:class:`jax.sharding.NamedSharding`, and
-:func:`jax.experimental.shard_map.shard_map`. The removed
-``jax.sharding.AxisType`` is deliberately not referenced anywhere.
+Built on :func:`jax.make_mesh` with ``AxisType.Auto`` axes (the default
+is now Explicit, which would make every jit outside a mesh context fail),
+:class:`jax.sharding.NamedSharding` and :func:`jax.shard_map` with its
+varying-manual-axes check (``check_vma``) on.
 
 Runs on CPU hosts via forced host devices, which is how CI exercises it:
 
@@ -41,8 +41,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 import jax.numpy as jnp
 
@@ -73,7 +72,8 @@ def make_series_mesh(
             f"requested {n} devices but {len(devs)} are available; on CPU "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=<n> "
             "before the first jax call")
-    return jax.make_mesh((n,), (axis_name,), devices=devs[:n])
+    return jax.make_mesh((n,), (axis_name,), (AxisType.Auto,),
+                         devices=devs[:n])
 
 
 def esrnn_param_specs(params, *, axis_name: str = SERIES_AXIS):
@@ -97,6 +97,14 @@ def esrnn_param_shardings(mesh: Mesh, params, *, axis_name: str = SERIES_AXIS):
         esrnn_param_specs(params, axis_name=axis_name),
         is_leaf=lambda x: isinstance(x, P),
     )
+
+
+def _check_vma(cfg: ESRNNConfig) -> bool:
+    """Whether ``shard_map`` checks varying manual axes: everywhere except
+    for kernels run by the Pallas interpreter (off the TPU), whose internal
+    loop does not carry the varying axes of its inputs. Natively compiled
+    kernels declare them on their outputs and run checked."""
+    return not (cfg.use_pallas and jax.default_backend() != "tpu")
 
 
 def check_series_divisible(n: int, mesh: Mesh) -> int:
@@ -151,13 +159,10 @@ def esrnn_loss_dp(
         return (pin_sum / jnp.maximum(pin_cnt, 1.0)
                 + jax.lax.pmean(penalties, axis_name))
 
-    # pallas_call has no shard_map replication rule; the loss is explicitly
-    # reduced to a replicated scalar above, so skipping the static rep check
-    # on the kernel path is sound (the default path keeps it).
-    return shard_map(
+    return jax.shard_map(
         local_loss, mesh=mesh,
         in_specs=(pspecs,) + (P(axis_name),) * len(rows), out_specs=P(),
-        check_rep=not cfg.use_pallas,
+        check_vma=_check_vma(cfg),
     )(params, *rows)
 
 
@@ -170,17 +175,15 @@ def _shard_rows(cfg, local_fn, params, rows, *, mesh, axis_name, out_specs):
     """shard_map a per-shard row function over the series axis.
 
     ``params`` shard like training (hw rows device-local, shared weights
-    replicated); every array in ``rows`` leads with the series axis. The
-    static replication check is skipped only on the kernel path, exactly as
-    in :func:`esrnn_loss_dp` (pallas_call has no replication rule).
+    replicated); every array in ``rows`` leads with the series axis.
     """
     check_series_divisible(rows[0].shape[0], mesh)
     pspecs = esrnn_param_specs(params, axis_name=axis_name)
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(pspecs,) + (P(axis_name),) * len(rows),
         out_specs=out_specs,
-        check_rep=not cfg.use_pallas,
+        check_vma=_check_vma(cfg),
     )(params, *rows)
 
 

@@ -20,7 +20,7 @@ def test_clean_f32_program_passes():
 
 def test_f64_promotion_is_flagged():
     """Seeded violation: an x64-enabled program producing float64 values."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def f(x):
             return x.astype(jnp.float64) * 2.0
 
@@ -83,7 +83,7 @@ def test_state_dtype_allows_declared_accumulation_upcasts():
 
 
 def test_state_dtype_still_flags_f64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def f(x):
             return x.astype(jnp.float64) * 2.0
 

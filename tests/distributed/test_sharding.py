@@ -71,13 +71,14 @@ import numpy as np
 
 from repro.configs import ShapeCell, get_smoke_config
 from repro.launch import steps as S
+from repro.launch.mesh import make_host_mesh
 from repro.models.model import build_model
 from repro.roofline import analysis
 from repro.roofline.jaxpr_cost import jaxpr_flops
 from repro.sharding import specs
 from repro.sharding.ctx import activation_sharding
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(model_parallel=2)
 cfg = get_smoke_config("yi-6b")
 cell = ShapeCell("t", "train", 32, 8, microbatch=4)
 model = build_model(cfg)

@@ -1,8 +1,39 @@
 """repro.launch.forecast CLI smoke: every subcommand end-to-end on CPU."""
 
-import pytest
+import os
 
-from repro.launch.forecast import main
+import jax
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+
+from repro.launch.forecast import main, use_compile_cache
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_cache_in_tmp(tmp_path_factory):
+    """``main`` turns on JAX's persistent compilation cache: keep it in a
+    temp dir here, and hand the process back without one."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("JAX_COMPILATION_CACHE_DIR",
+                  str(tmp_path_factory.mktemp("jax_cache")))
+        yield
+    jax.config.update("jax_compilation_cache_dir", None)
+    compilation_cache.reset_cache()
+
+
+def test_main_keeps_compile_cache_where_env_says(capsys):
+    assert main(["specs"]) == 0
+    assert (jax.config.jax_compilation_cache_dir
+            == os.environ["JAX_COMPILATION_CACHE_DIR"])
+
+
+def test_compile_cache_defaults_to_a_fixed_repo_dir(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    assert use_compile_cache() == os.path.join(root, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == os.path.join(
+        root, ".jax_cache")
 
 
 @pytest.fixture(scope="module")

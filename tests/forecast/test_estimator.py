@@ -112,6 +112,18 @@ def test_evaluate_reports_owa_vs_benchmarks(fitted):
     assert val["split"] == "val" and np.isfinite(val["smape"])
 
 
+def test_inference_raises_when_data_parallel_exceeds_devices():
+    """Like fit, predict refuses a spec that asks for more devices than the
+    host has, rather than quietly running on one."""
+    n_dev = len(jax.devices())
+    f = ESRNNForecaster(get_smoke_spec("esrnn-quarterly",
+                                       data_parallel=n_dev + 1))
+    f.init_params(4)
+    y = np.full((4, 24), 100.0, np.float32)
+    with pytest.raises(ValueError, match="devices"):
+        f.predict(y)
+
+
 def test_unfitted_raises():
     f = ESRNNForecaster(get_smoke_spec("esrnn-quarterly"))
     with pytest.raises(NotFittedError):

@@ -3,12 +3,10 @@
 The kernel carries a custom_vjp whose backward is the time-reversed adjoint
 recurrence (kernels/hw_scan.py). Gradient coverage here: analytic-vs-autodiff
 equivalence against the pure-jnp oracle, finite-difference spot checks on the
-raw kernel cotangents, pad-lane gradient isolation, and the CPU
-``_vmem_scratch`` fallback exercised for real in interpret mode.
+raw kernel cotangents, and pad-lane gradient isolation.
 """
 
 import dataclasses
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -189,52 +187,3 @@ def test_pad_lane_grads_are_isolated():
     np.testing.assert_array_equal(np.asarray(g_sub_p.init_seas_logit),
                                   np.asarray(g_full_p.init_seas_logit)[:n_sub])
 
-
-# ---------------------------------------------------------------------------
-# _vmem_scratch CPU fallback
-# ---------------------------------------------------------------------------
-
-
-def test_vmem_scratch_fallback_is_constructible():
-    """The no-pltpu fallback must build a real scratch allocation.
-
-    Regression: it used to call ``pl.MemorySpace.ANY(shape, dtype)``, which
-    is an enum member and not callable (TypeError hidden behind
-    ``type: ignore`` + ``pragma: no cover``).
-    """
-    from jax.experimental import pallas as pl
-
-    ref = pl.MemoryRef((4, 128), jnp.dtype(jnp.float32), pl.MemorySpace.ANY)
-    assert ref.memory_space == pl.MemorySpace.ANY
-    with pytest.raises(TypeError):
-        pl.MemorySpace.ANY((4, 128), jnp.float32)  # the old broken call
-
-
-def test_vmem_scratch_fallback_runs_in_interpret_mode(monkeypatch):
-    """Force the except path and run the kernel end-to-end on it."""
-    import jax.experimental.pallas as pl_pkg
-
-    # make `from jax.experimental.pallas import tpu` fail inside
-    # _vmem_scratch: drop the already-bound attribute and poison sys.modules
-    monkeypatch.delattr(pl_pkg, "tpu", raising=False)
-    monkeypatch.setitem(sys.modules, "jax.experimental.pallas.tpu", None)
-    with pytest.raises(ImportError):
-        from jax.experimental.pallas import tpu  # noqa: F401
-
-    fallback = hw_scan_mod._vmem_scratch((4, 128), jnp.float32)
-    from jax.experimental import pallas as pl
-
-    assert isinstance(fallback, pl.MemoryRef)
-    assert fallback.memory_space == pl.MemorySpace.ANY
-
-    # odd T so the jit cache cannot reuse a trace built with pltpu.VMEM
-    y, p = _setup(130, 31, 4, seed=8, dtype=jnp.float32)
-    hw_scan_mod.hw_scan_tm.clear_cache()
-    try:
-        lv, ss = ops.hw_scan(y, p, seasonality=4)
-        c = p.constrained()
-        lv_ref, ss_ref = hw_scan_ref(y, c["alpha"], c["gamma"], c["init_seas"])
-        np.testing.assert_allclose(lv, lv_ref, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(ss, ss_ref, rtol=1e-5, atol=1e-5)
-    finally:
-        hw_scan_mod.hw_scan_tm.clear_cache()

@@ -44,6 +44,17 @@ def _max_leaf_diff(tree_a, tree_b):
         lambda a, b: jnp.max(jnp.abs(a - b)), tree_a, tree_b))))
 
 
+def test_dual_seasonality_has_no_kernel_and_raises():
+    """Hourly's second ring has no kernel: ``use_pallas`` must say so, not
+    quietly run the jnp scan."""
+    from repro.core.holt_winters import hw_init_params, hw_smooth
+
+    p = hw_init_params(4, 24, seasonality2=168)
+    y = jnp.ones((4, 200), jnp.float32)
+    with pytest.raises(NotImplementedError, match="dual-seasonality"):
+        hw_smooth(y, p, seasonality=24, seasonality2=168, use_pallas=True)
+
+
 def test_interpret_mode_is_selected_off_tpu():
     if jax.default_backend() != "tpu":
         assert ops._interpret()
