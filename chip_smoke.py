@@ -13,16 +13,17 @@ weights start random from the same seed.
 
 One chip:
 
-1. fit ``--steps`` steps on the default (``jax.numpy``) path;
-2. the same fit through the Pallas kernels (``use_pallas=True``). The
-   compiled train step must hold ``tpu_custom_call`` (the kernels were
-   compiled by Mosaic, not interpreted), and the loss trajectory must stay
-   within ``KERNEL_RTOL`` of phase 1; so must a forecast through the
-   kernels against the ``jax.numpy`` forecast at the same weights;
+1. fit ``STEPS`` steps on the default (``jax.numpy``) path;
+2. the same fit through the Pallas kernels (``use_pallas=True``). The train
+   step that fit compiled must hold the forward and backward kernels as
+   ``tpu_custom_call``s (compiled by Mosaic, not interpreted). Against the
+   default path, within ``KERNEL_RTOL``: the loss trajectory, and a
+   forecast at the same weights; within ``GRAD_RTOL``: one batch's
+   gradient at the initial weights, leaf by leaf;
 3. predict all series and ``evaluate(split="test")``: finite forecasts,
    sMAPE and OWA;
-4. two waves of requests through ``ForecastServer``: every forecast finite,
-   and the second wave compiles nothing.
+4. two waves of ``REQUESTS`` requests through ``ForecastServer``: every
+   forecast finite, and the second wave compiles nothing.
 
 ``--chips 4`` runs only a 4-way series-data-parallel fit and a sharded
 predict, against a single-device fit and predict on the first of those
@@ -38,7 +39,9 @@ import argparse
 import dataclasses
 import json
 import os
+import re
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -55,9 +58,17 @@ import numpy as np  # noqa: E402
 # kernels sum in another order than XLA's autodiff (weight gradients
 # accumulate tile by tile over the batch grid), which moves every update by
 # fp32 rounding; 20 Adam steps carry that to about 1e-5 of the loss. 1e-4
-# leaves a factor of ten over that and stays far below what a wrong
-# gradient term does to the trajectory.
+# leaves a factor of ten over that.
 KERNEL_RTOL = 1e-4
+# One batch's gradient at the initial weights, kernel vs default path, per
+# leaf: max |a - b| over max |b|. The trajectory alone cannot show a wrong
+# backward kernel: 20 batches of 256 out of 8572 series revisit few rows, so
+# a wrong Holt-Winters gradient barely reaches the loss, and a dropped LSTM
+# cell-state cotangent moves it by less than KERNEL_RTOL. Such a fault (a
+# term dropped or its sign flipped in either backward kernel) moves this
+# gradient by 0.8 to 2; summation order and bf16 operand rounding move it by
+# orders of magnitude less. 1e-2 lies between the two.
+GRAD_RTOL = 1e-2
 # Sharded vs single device, relative. Both run the same per-row math at the
 # same precision; only the order of the cross-device sums (gradient psum,
 # loss pmean) differs, at the fp32 rounding scale (~1e-7) per step, which
@@ -65,6 +76,8 @@ KERNEL_RTOL = 1e-4
 # the same weights has no cross-device sum at all.
 DP_RTOL = 1e-4
 
+STEPS = 20
+REQUESTS = 48
 SPEC = "esrnn-quarterly"
 
 
@@ -83,24 +96,10 @@ def rel_diff(a, b) -> float:
     return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
 
 
-class CompileClock:
-    """Seconds XLA spends in backend compiles, summed over the process."""
-
-    def __init__(self):
-        from repro.analysis.recompile import COMPILE_EVENT
-
-        self.event, self.seconds = COMPILE_EVENT, 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **kwargs) -> None:
-        if event == self.event:
-            self.seconds += duration
-
-
 class Phase:
     """Times one phase: wall seconds and XLA compile seconds inside it."""
 
-    def __init__(self, name: str, clock: CompileClock):
+    def __init__(self, name: str, clock):
         self.name, self.clock = name, clock
 
     def __enter__(self):
@@ -125,15 +124,52 @@ def fit(spec, data):
     return f, losses
 
 
-def step_hlo(spec, data, params) -> str:
-    """Compiled HLO of the train step that ``fit`` runs."""
-    from repro.train.engine import make_step_fn
-    from repro.train.optimizer import AdamConfig, adam_init
+# the jitted train step the trainer dispatches (per-step or fused engine)
+STEP_MODULE = re.compile(r"jax_ir\d+_jit_(super)?step_compile\.mlir")
+# the forward and backward halves of both kernels on the fit's main path
+TRAIN_KERNELS = ("_hw_scan_kernel", "_hw_scan_bwd_kernel",
+                 "_lstm_fwd_kernel", "_lstm_bwd_kernel")
 
-    step = make_step_fn(spec.model, AdamConfig(), jnp.asarray(data.train),
-                        jnp.asarray(data.cats), jnp.asarray(data.mask))
-    idx = jnp.arange(spec.batch_size, dtype=jnp.int32)
-    return jax.jit(step).lower(params, adam_init(params), idx).compile().as_text()
+
+def fit_with_step_kernels(spec, data):
+    """Fit, and name the TPU kernels in the train step that fit compiled.
+
+    JAX writes every module it compiles to ``jax_dump_ir_to`` while that is
+    set; the train step's module is then the program the fit ran.
+    """
+    prev = jax.config.values.get("jax_dump_ir_to", "")
+    with tempfile.TemporaryDirectory() as d:
+        jax.config.update("jax_dump_ir_to", d)
+        try:
+            f, losses = fit(spec, data)
+        finally:
+            jax.config.update("jax_dump_ir_to", prev)
+        steps = [n for n in os.listdir(d) if STEP_MODULE.fullmatch(n)]
+        check(len(steps) == 1, f"the fit compiled train steps {steps}")
+        with open(os.path.join(d, steps[0])) as fh:
+            text = fh.read()
+    calls = [ln for ln in text.splitlines() if "@tpu_custom_call" in ln]
+    kernels = sorted(k for ln in calls
+                     for k in re.findall(r'kernel_name = "(\w+)"', ln))
+    return f, losses, len(calls), kernels
+
+
+def grad_diffs(spec, kspec, data, seed: int) -> dict:
+    """One batch's loss gradient at the initial weights, kernel path vs
+    default path: the largest per-leaf relative difference in each group."""
+    from repro.core.esrnn import esrnn_loss_and_grad, gather_series
+    from repro.forecast import ESRNNForecaster
+
+    params = ESRNNForecaster(spec).init_params(data.n_series, seed=seed)
+    idx = jnp.arange(spec.batch_size)
+    batch = [jnp.asarray(a)[idx] for a in (data.train, data.cats, data.mask)]
+    p_b = gather_series(params, idx)
+    _, g_ref = esrnn_loss_and_grad(spec.model, p_b, *batch)
+    _, g_k = esrnn_loss_and_grad(kspec.model, p_b, *batch)
+    return {group: max(rel_diff(a, b) for a, b in zip(
+                jax.tree_util.tree_leaves(g_k[group]),
+                jax.tree_util.tree_leaves(g_ref[group])))
+            for group in g_ref}
 
 
 def one_chip(args, clock) -> None:
@@ -142,20 +178,21 @@ def one_chip(args, clock) -> None:
     from repro.forecast.serving import synthetic_request_stream
 
     spec = get_spec(SPEC, data_scale=1.0, data_seed=args.seed, seed=args.seed,
-                    n_steps=args.steps)
+                    n_steps=STEPS)
     with Phase("data", clock):
         data = ESRNNForecaster(spec).make_data()
         print(f"   {data.n_series} series, T={data.train.shape[1]}")
 
-    with Phase(f"fit, default path, {args.steps} steps", clock):
+    with Phase(f"fit, default path, {STEPS} steps", clock):
         f, loss_jnp = fit(spec, data)
 
-    with Phase(f"fit, Pallas kernels, {args.steps} steps", clock):
+    with Phase(f"fit, Pallas kernels, {STEPS} steps", clock):
         kspec = spec.replace(use_pallas=True)
-        g, loss_k = fit(kspec, data)
-        n_calls = step_hlo(kspec, data, g.params_).count("tpu_custom_call")
-        print(f"   compiled train step: {n_calls} tpu_custom_call")
-        check(n_calls > 0, "the use_pallas train step holds no TPU kernel")
+        g, loss_k, n_calls, kernels = fit_with_step_kernels(kspec, data)
+        print(f"   the fit's train step: {n_calls} tpu_custom_call, kernels "
+              f"{kernels}")
+        missing = sorted(set(TRAIN_KERNELS) - set(kernels))
+        check(not missing, f"the use_pallas train step lacks {missing}")
         d_loss = rel_diff(loss_k, loss_jnp)
         y, cats = jnp.asarray(data.train), jnp.asarray(data.cats)
         fc_k = g.predict()
@@ -163,8 +200,14 @@ def one_chip(args, clock) -> None:
         d_fc = rel_diff(fc_k, fc_ref)
         print(f"   kernel vs default: loss trajectory rel diff {d_loss:.3e}, "
               f"forecast rel diff {d_fc:.3e} (tolerance {KERNEL_RTOL:g})")
+        d_grad = grad_diffs(spec, kspec, data, args.seed)
+        print("   kernel vs default: one batch's gradient, max per-leaf rel "
+              "diff " + ", ".join(f"{k} {v:.3e}" for k, v in d_grad.items())
+              + f" (tolerance {GRAD_RTOL:g})")
         check(d_loss <= KERNEL_RTOL, f"kernel loss trajectory off by {d_loss}")
         check(d_fc <= KERNEL_RTOL, f"kernel forecast off by {d_fc}")
+        check(max(d_grad.values()) <= GRAD_RTOL,
+              f"kernel gradient off by {d_grad}")
 
     with Phase("predict + evaluate(test)", clock):
         fc = f.predict()
@@ -175,10 +218,10 @@ def one_chip(args, clock) -> None:
               f"OWA {sc['owa']:.4f} (Comb OWA {sc['owa_comb']:.4f})")
         check(np.isfinite([sc["smape"], sc["owa"]]).all(), f"scores {sc}")
 
-    with Phase(f"serve, 2 waves of {args.requests} requests", clock):
+    with Phase(f"serve, 2 waves of {REQUESTS} requests", clock):
         srv = f.serve()
         wave1 = synthetic_request_stream(
-            spec.model, args.requests, n_known=f.n_series_, seed=args.seed)
+            spec.model, REQUESTS, n_known=f.n_series_, seed=args.seed)
         # new values at the first wave's lengths: every bucket it needs is
         # already compiled
         rng = np.random.default_rng(args.seed + 1)
@@ -208,14 +251,14 @@ def four_chips(args, clock) -> None:
     n_dev = len(jax.devices())
     check(n_dev == 4, f"--chips 4 needs 4 devices, JAX sees {n_dev}")
     spec = get_spec(SPEC, data_scale=1.0, data_seed=args.seed, seed=args.seed,
-                    n_steps=args.steps)
+                    n_steps=STEPS)
     with Phase("data", clock):
         data = ESRNNForecaster(spec).make_data()
         print(f"   {data.n_series} series, T={data.train.shape[1]}")
 
-    with Phase(f"fit, 4-way data parallel, {args.steps} steps", clock):
+    with Phase(f"fit, 4-way data parallel, {STEPS} steps", clock):
         dp, loss_dp = fit(spec.replace(data_parallel=4), data)
-    with Phase(f"fit, one device, {args.steps} steps", clock):
+    with Phase(f"fit, one device, {STEPS} steps", clock):
         one, loss_1 = fit(spec, data)
     d_loss = rel_diff(loss_dp, loss_1)
     print(f"   4-way vs one device: loss trajectory rel diff {d_loss:.3e} "
@@ -241,8 +284,6 @@ def four_chips(args, clock) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--steps", type=int, default=20)
-    ap.add_argument("--requests", type=int, default=48)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -253,12 +294,13 @@ def main(argv=None) -> int:
     if dev.platform != "tpu":
         fail(f"JAX found no TPU (platform {dev.platform!r})")
 
+    from repro.analysis.recompile import CompileCounter
     from repro.launch.forecast import use_compile_cache
 
     print(f"compile cache: {use_compile_cache()}")
-    clock = CompileClock()
     t0 = time.perf_counter()
-    (four_chips if args.chips == 4 else one_chip)(args, clock)
+    with CompileCounter() as clock:
+        (four_chips if args.chips == 4 else one_chip)(args, clock)
     print(f"total wall {time.perf_counter() - t0:.1f} s, compile "
           f"{clock.seconds:.1f} s")
     print(json.dumps({"ok": True, "device": device}))

@@ -49,7 +49,7 @@ def _install_listener() -> None:
             with _lock:
                 counters = list(_active)
             for counter in counters:
-                counter._bump()
+                counter._bump(duration)
 
         monitoring.register_event_duration_secs_listener(_on_duration)
         _listener_installed = True
@@ -60,17 +60,20 @@ class CompileCounter:
 
     Counts every backend compile in the process during the armed window
     (that is the point: the ``fc[:n]`` family was invisible to any
-    per-callable accounting). Optionally mirrors each count into a
+    per-callable accounting) and sums their durations in ``seconds``.
+    Optionally mirrors each count into a
     :class:`~repro.forecast.serving.ServeStats` via ``stats`` so serving
     telemetry reports true XLA compiles next to its bucket-grid intent.
     """
 
     def __init__(self, stats=None):
         self.count = 0
+        self.seconds = 0.0
         self._stats = stats
 
-    def _bump(self) -> None:
+    def _bump(self, duration: float) -> None:
         self.count += 1
+        self.seconds += duration
         if self._stats is not None:
             self._stats.xla_compiles += 1
 

@@ -50,6 +50,7 @@ from repro.core.esrnn import (
     ESRNNConfig, esrnn_forecast_at_fn, esrnn_forecast_fn, esrnn_loss_terms_fn,
     esrnn_predict_stats_fn,
 )
+from repro.kernels import ops as kernel_ops
 
 SERIES_AXIS = "series"
 
@@ -101,10 +102,10 @@ def esrnn_param_shardings(mesh: Mesh, params, *, axis_name: str = SERIES_AXIS):
 
 def _check_vma(cfg: ESRNNConfig) -> bool:
     """Whether ``shard_map`` checks varying manual axes: everywhere except
-    for kernels run by the Pallas interpreter (off the TPU), whose internal
-    loop does not carry the varying axes of its inputs. Natively compiled
-    kernels declare them on their outputs and run checked."""
-    return not (cfg.use_pallas and jax.default_backend() != "tpu")
+    for kernels run by the Pallas interpreter, whose internal loop does not
+    carry the varying axes of its inputs. Natively compiled kernels declare
+    them on their outputs and run checked."""
+    return not (cfg.use_pallas and kernel_ops._interpret())
 
 
 def check_series_divisible(n: int, mesh: Mesh) -> int:

@@ -6,6 +6,8 @@ These tests lower ``hw_scan_tm`` and ``lstm_cell_padded`` -- forward and
 custom_vjp backward, at the widths the forecaster runs -- with
 ``interpret=False`` against a ``v5e:2x2`` topology description, and assert
 that the compiled program holds the Mosaic kernel (``tpu_custom_call``).
+One more compiles the 4-way series-sharded loss gradient through the
+kernels, with ``shard_map``'s varying-axes check on as it is on a TPU.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler library, and every xdist
@@ -17,23 +19,44 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from repro.core.esrnn import esrnn_init
+from repro.forecast import get_spec
 from repro.kernels import hw_scan as _hw
 from repro.kernels import lstm_cell as _lstm
+from repro.kernels import ops as _ops
+from repro.sharding import series as _series
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Route ``kernels.ops`` to the compiled kernels, as on a TPU.
+
+    The backend here is the CPU, so the wrappers would pick interpret mode;
+    jit caches are cleared on both sides so no trace crosses the switch.
+    """
+    monkeypatch.setattr(_ops, "_interpret", lambda: False)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
 
 
 def _compiled_text(fn, *shapes):
@@ -83,3 +106,26 @@ def test_lstm_cell_compiles_for_v5e(one_chip, dtype):
     assert "tpu_custom_call" in _compiled_text(fwd, *args)
     grad_text = _compiled_text(jax.grad(loss, argnums=tuple(range(6))), *args)
     assert grad_text.count("tpu_custom_call") >= 2
+
+
+def test_sharded_kernel_loss_grad_compiles_for_v5e_2x2(topo, mosaic):
+    cfg = get_spec("esrnn-quarterly", use_pallas=True).model
+    assert _series._check_vma(cfg)
+    mesh = _series.make_series_mesh(4, devices=topo.devices)
+    b, t_len = 256, 72
+    params = jax.eval_shape(
+        lambda: esrnn_init(jax.random.PRNGKey(0), cfg, b))
+    params = jax.tree_util.tree_map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        params, _series.esrnn_param_shardings(mesh, params))
+    rows = NamedSharding(mesh, P(_series.SERIES_AXIS))
+    y = jax.ShapeDtypeStruct((b, t_len), jnp.float32, sharding=rows)
+    cats = jax.ShapeDtypeStruct((b, cfg.n_categories), jnp.float32,
+                                sharding=rows)
+
+    def loss(p, y, cats, mask):
+        return _series.esrnn_loss_dp(cfg, p, y, cats, mask, mesh=mesh)
+
+    text = _compiled_text(jax.grad(loss), params, y, cats, y)
+    assert text.count("tpu_custom_call") >= 4   # both kernels, both ways
+    assert "all-reduce" in text
