@@ -199,16 +199,19 @@ def esrnn_states(cfg, params, y, cats) -> ESRNNStates:
     registered since). Every head must be causal along the position axis,
     which is what keeps :func:`forecast_at_origins` sound.
     """
-    levels, seas = smooth(cfg, params, y)
-    x_in, pos = input_windows(cfg, y, levels, seas)
-    feats = features(x_in, cats)
+    with jax.named_scope("esrnn.hw"):
+        levels, seas = smooth(cfg, params, y)
+    with jax.named_scope("esrnn.windows"):
+        x_in, pos = input_windows(cfg, y, levels, seas)
+        feats = features(x_in, cats)
     # The head computes in the policy's dtype (bf16 halves every activation
     # and weight tile it streams); its readout re-emits yhat_n in fp32 so the
     # pinball reduction and the Eq.-5 exp stay full precision.
     cdt = cfg.compute_dtype
     if feats.dtype != cdt:
         feats = feats.astype(cdt)
-    yhat_n, c_sq = H.get_head(cfg.head).apply(cfg, params, feats)
+    with jax.named_scope("esrnn.head"):
+        yhat_n, c_sq = H.get_head(cfg.head).apply(cfg, params, feats)
     return ESRNNStates(levels=levels, seas=seas, pos=pos, x_in=x_in,
                        yhat_n=yhat_n, c_sq=c_sq)
 
@@ -245,11 +248,12 @@ def forecast_from_states(cfg, states: ESRNNStates, t_len: int):
     future seasonality extended by the :func:`future_seasonal_idx` cyclic
     rule at the final position T-1 (indices T..T+H-1).
     """
-    last = states.yhat_n[:, -1, :]                       # (N, H) log-space
-    m = max(cfg.seasonality, 1)
-    fut_idx = t_len + jnp.arange(cfg.output_size)        # targets of pos T-1
-    s_fut = states.seas[:, future_seasonal_idx(fut_idx, t_len, m)]
-    return jnp.exp(last) * states.levels[:, -1:] * s_fut
+    with jax.named_scope("esrnn.readout"):
+        last = states.yhat_n[:, -1, :]                   # (N, H) log-space
+        m = max(cfg.seasonality, 1)
+        fut_idx = t_len + jnp.arange(cfg.output_size)    # targets of pos T-1
+        s_fut = states.seas[:, future_seasonal_idx(fut_idx, t_len, m)]
+        return jnp.exp(last) * states.levels[:, -1:] * s_fut
 
 
 def quantile_sigma(states: ESRNNStates, y):

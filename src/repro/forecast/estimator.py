@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import spans
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.core import losses as L
 from repro.core.comb import comb_forecast, naive2_forecast
@@ -238,18 +239,38 @@ class ESRNNForecaster:
         shard): the chunk's rows are padded to the device multiple and the
         pad stripped, exactly like resident sharded inference.
         """
-        rows = y.shape[0]
-        p_c = {k: (jax.tree_util.tree_map(jnp.asarray, v) if k == "hw" else v)
-               for k, v in params.items()}
-        y = jnp.asarray(y)
-        cats = jnp.asarray(cats)
-        if mesh is None:
-            return np.asarray(esrnn_forecast(self.config, p_c, y, cats))
-        from repro.sharding.series import esrnn_forecast_dp
+        on = spans.recording()
+        with spans.span("predict.inputs", on=on):
+            p_c = {k: (jax.tree_util.tree_map(jnp.asarray, v) if k == "hw"
+                       else v) for k, v in params.items()}
+            y = jnp.asarray(y)
+            cats = jnp.asarray(cats)
+        return self._forecast_rows(p_c, y, cats, mesh, on)
 
-        p_c, (y, cats), _pad = self._shard_rows(p_c, (y, cats), mesh)
-        return np.asarray(
-            esrnn_forecast_dp(self.config, p_c, y, cats, mesh=mesh))[:rows]
+    def _forecast_rows(self, params, y, cats, mesh, on: bool):
+        """Forecast rows whose copies to the device are under way: (rows, H)
+        numpy out, under the spans ``predict.forecast`` (the dispatch),
+        ``predict.transfer`` and ``predict.result`` (the read back). The
+        copies run on a worker thread, so the caller's ``predict.inputs``
+        holds only their start; while a trace runs, ``predict.transfer``
+        waits for them after the dispatch, when the forecast already stands
+        behind them on the device, so the wait moves nothing there."""
+        rows = y.shape[0]
+        with spans.span("predict.forecast", on=on):
+            if mesh is None:
+                fc = esrnn_forecast(self.config, params, y, cats)
+            else:
+                from repro.sharding.series import esrnn_forecast_dp
+
+                params, (y, cats), _pad = self._shard_rows(
+                    params, (y, cats), mesh)
+                fc = esrnn_forecast_dp(self.config, params, y, cats,
+                                       mesh=mesh)
+        if on:
+            with spans.span("predict.transfer", on=on):
+                jax.block_until_ready((y, cats))
+        with spans.span("predict.result", on=on):
+            return np.asarray(fc)[:rows]
 
     def predict(self, y=None, cats=None, *,
                 series_idx: Optional[Sequence[int]] = None,
@@ -273,25 +294,22 @@ class ESRNNForecaster:
         """
         mesh = self._resolve_mesh(mesh)
         n_in = (self.n_series_ if y is None else np.shape(y)[0])
-        if series_idx is None and self._chunk_ranges(n_in or 0):
-            params, y, cats = self._resolve_inputs(y, cats, None, host=True)
-            out = np.empty((y.shape[0], self.horizon), np.float32)
-            shared = {k: v for k, v in params.items() if k != "hw"}
-            for lo, hi in self._chunk_ranges(y.shape[0]):
-                p_c = {"hw": jax.tree_util.tree_map(
-                    lambda a: a[lo:hi], params["hw"]), **shared}
-                out[lo:hi] = self._forecast_chunk(
-                    p_c, y[lo:hi], cats[lo:hi], mesh)
-            return out
-        params, y, cats = self._resolve_inputs(y, cats, series_idx)
-        if mesh is None:
-            return np.asarray(esrnn_forecast(self.config, params, y, cats))
-        from repro.sharding.series import esrnn_forecast_dp
-
-        n = y.shape[0]
-        params, (y, cats), _pad = self._shard_rows(params, (y, cats), mesh)
-        fc = esrnn_forecast_dp(self.config, params, y, cats, mesh=mesh)
-        return np.asarray(fc)[:n]
+        on = spans.recording()
+        with spans.span("predict.call", leaf=False, on=on):
+            if series_idx is None and self._chunk_ranges(n_in or 0):
+                params, y, cats = self._resolve_inputs(y, cats, None,
+                                                       host=True)
+                out = np.empty((y.shape[0], self.horizon), np.float32)
+                shared = {k: v for k, v in params.items() if k != "hw"}
+                for lo, hi in self._chunk_ranges(y.shape[0]):
+                    p_c = {"hw": jax.tree_util.tree_map(
+                        lambda a: a[lo:hi], params["hw"]), **shared}
+                    out[lo:hi] = self._forecast_chunk(
+                        p_c, y[lo:hi], cats[lo:hi], mesh)
+                return out
+            with spans.span("predict.inputs", on=on):
+                params, y, cats = self._resolve_inputs(y, cats, series_idx)
+            return self._forecast_rows(params, y, cats, mesh, on)
 
     def predict_quantiles(
         self, y=None, cats=None, *, taus: Tuple[float, ...] = (0.1, 0.5, 0.9),

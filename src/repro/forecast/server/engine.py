@@ -35,6 +35,12 @@ The scheduler is single-threaded (one dispatching thread, or the caller's
 thread via :meth:`step`/:meth:`drain` for deterministic tests), which keeps
 ``ServeStats`` single-writer and the store free of fine-grained locking:
 the only lock is the queue's own condition variable.
+
+A scheduler pass records the spans ``serve.absorb`` (when writes wait),
+``serve.group``, and per dispatched chunk a ``serve.dispatch`` holding the
+dispatcher's own spans and ``serve.handoff`` (``repro.analysis.spans``);
+while a trace runs, each answered request's wait from submit to its
+dispatch is kept as a ``serve.queue_wait`` sample.
 """
 
 from __future__ import annotations
@@ -46,6 +52,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.analysis import spans
 from repro.core.esrnn import ESRNNConfig
 from repro.forecast.serving import (
     BucketDispatcher, ForecastRequest, ServeStats,
@@ -237,7 +244,8 @@ class ForecastServer:
             writes, self._writes = self._writes, []
         if not writes:
             return 0
-        n = self.store.absorb(writes, self.dispatcher.resolve_row)
+        with spans.span("serve.absorb"):
+            n = self.store.absorb(writes, self.dispatcher.resolve_row)
         self.stats.observes += n
         self.stats.write_batches += 1
         self._active_since_tune = True
@@ -277,12 +285,13 @@ class ForecastServer:
         # group by length bucket, resolving online histories after the write
         # absorption above (read-your-writes ordering)
         groups: Dict[int, List[Tuple[_Pending, np.ndarray]]] = {}
-        for entry in pending:
-            hist = self._resolve_history(entry)
-            if hist is None:
-                continue
-            b = self.dispatcher.pick_length_bucket(len(hist))
-            groups.setdefault(b, []).append((entry, hist))
+        with spans.span("serve.group"):
+            for entry in pending:
+                hist = self._resolve_history(entry)
+                if hist is None:
+                    continue
+                b = self.dispatcher.pick_length_bucket(len(hist))
+                groups.setdefault(b, []).append((entry, hist))
 
         now = time.perf_counter()
         max_wait_s = self.server_config.max_wait_ms / 1e3
@@ -299,18 +308,27 @@ class ForecastServer:
             t0 = time.perf_counter()
             for lo in range(0, len(entries), max_batch):
                 chunk = entries[lo:lo + max_batch]
-                reqs = [dataclasses.replace(e.request, y=h)
-                        for e, h in chunk]
-                try:
-                    fc = self.dispatcher.run_bucket(reqs, bucket)
-                except Exception as err:     # the batch fails, not the server
-                    for e, _ in chunk:
-                        e.future.set_exception(err)
-                    continue
-                done_t = time.perf_counter()
-                for j, (e, _) in enumerate(chunk):
-                    e.future.set_result(fc[j])
-                    self.stats.record_latency(done_t - e.arrival)
+                with spans.span("serve.dispatch", leaf=False,
+                                batch=self.stats.batches,
+                                rows=len(chunk)) as d:
+                    reqs = [dataclasses.replace(e.request, y=h)
+                            for e, h in chunk]
+                    try:
+                        fc = self.dispatcher.run_bucket(reqs, bucket)
+                    except Exception as err:  # the batch fails, not the server
+                        for e, _ in chunk:
+                            e.future.set_exception(err)
+                        continue
+                    if d is not None:
+                        for e, _ in chunk:
+                            spans.sample("serve.queue_wait",
+                                         d.start - e.arrival,
+                                         batch=d.ids["batch"])
+                    with spans.span("serve.handoff", on=d is not None):
+                        done_t = time.perf_counter()
+                        for j, (e, _) in enumerate(chunk):
+                            e.future.set_result(fc[j])
+                            self.stats.record_latency(done_t - e.arrival)
                 completed += len(chunk)
             self.stats.total_s += time.perf_counter() - t0
         self.stats.requests += completed

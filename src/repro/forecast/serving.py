@@ -19,7 +19,8 @@ distinct (batch, length) -- fatal under heavy traffic. Instead:
 so the jit cache holds at most ``len(length_buckets) * len(batch_buckets)``
 entries and every subsequent request is a cache hit. ``ServeStats`` reports
 the hit/compile split to prove the reuse, plus per-request latency
-percentiles and queue gauges for the continuous-batching front end.
+percentiles and the queue's high-water mark for the continuous-batching
+front end.
 
 The module splits serving into two layers:
 
@@ -64,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import spans
 from repro.analysis.recompile import CompileCounter
 from repro.core.esrnn import ESRNNConfig, esrnn_forecast, esrnn_init
 
@@ -115,7 +117,6 @@ class ServeStats:
     observes: int = 0                # online observations absorbed
     write_batches: int = 0           # batched write-absorption passes
     finetunes: int = 0               # idle incremental fine-tune runs
-    queue_depth: int = 0             # gauge: pending requests at last pass
     queue_peak: int = 0              # high-water mark of the request queue
     total_s: float = 0.0
     latencies_s: Deque[float] = dataclasses.field(
@@ -132,7 +133,6 @@ class ServeStats:
         self.latencies_s.append(seconds)
 
     def note_queue_depth(self, depth: int) -> None:
-        self.queue_depth = depth
         self.queue_peak = max(self.queue_peak, depth)
 
     def reset(self) -> None:
@@ -147,7 +147,7 @@ class ServeStats:
                                      # declaration, not a counter
         self.padded_series = self.truncated_series = 0
         self.observes = self.write_batches = self.finetunes = 0
-        self.queue_depth = self.queue_peak = 0
+        self.queue_peak = 0
         self.total_s = 0.0
         self.latencies_s.clear()
 
@@ -314,6 +314,9 @@ class BucketDispatcher:
 
         Every request must carry a resolved history (``y`` not None) -- the
         online-store resolution happens upstream in the continuous server.
+        Its phases are the spans ``serve.shape`` (pad, one-hot, HW rows),
+        ``serve.launch`` (transfer and dispatch) and ``serve.result`` (the
+        read back, which waits on the device).
         """
         n = len(requests)
         # with a mesh, the buckets were snapped to the device multiple at
@@ -322,16 +325,18 @@ class BucketDispatcher:
         padded = requests + [requests[-1]] * (bb - n)
         self.stats.padded_series += bb - n
 
-        y = np.stack([self.shape_history(r.y, bucket) for r in padded])
-        cats = np.zeros((bb, self.config.n_categories), np.float32)
-        for row, r in enumerate(padded):
-            # out-of-range category -> all-zero one-hot (cold start, like an
-            # unknown series_id); never let one bad request fail the batch
-            if 0 <= r.category < self.config.n_categories:
-                cats[row, r.category] = 1.0
+        with spans.span("serve.shape"):
+            y = np.stack([self.shape_history(r.y, bucket) for r in padded])
+            cats = np.zeros((bb, self.config.n_categories), np.float32)
+            for row, r in enumerate(padded):
+                # out-of-range category -> all-zero one-hot (cold start, like
+                # an unknown series_id); never let one bad request fail the
+                # batch
+                if 0 <= r.category < self.config.n_categories:
+                    cats[row, r.category] = 1.0
 
-        hw = self.hw_rows(padded)
-        params = dict(self.params, hw=hw)
+            hw = self.hw_rows(padded)
+            params = dict(self.params, hw=hw)
 
         shape = (bb, bucket)
         if shape in self._seen_shapes:
@@ -344,13 +349,15 @@ class BucketDispatcher:
         # accounting above cannot see (the fc[:n] slice family was exactly
         # such an invisible compile per distinct partial fill)
         with self._xla_counter:
-            fc = self._forecast(params, jnp.asarray(y), jnp.asarray(cats))
+            with spans.span("serve.launch"):
+                fc = self._forecast(params, jnp.asarray(y), jnp.asarray(cats))
             self.stats.batches += 1
             # strip the batch padding on the HOST copy: fc[:n] on the device
             # array is a jitted slice op that XLA compiles once per distinct
             # partial fill n -- an unbounded compile family (~tens of ms
             # each) on the latency path. Transferring padded rows is cheap.
-            out = np.asarray(fc)[:n]
+            with spans.span("serve.result"):
+                out = np.asarray(fc)[:n]
         return out
 
     def forecast_batch(

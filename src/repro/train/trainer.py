@@ -15,7 +15,11 @@ Production posture:
   superstep); steps slower than ``straggler_factor``x the EWMA are logged
   (on real fleets this feeds the scheduler; here it exercises the code path),
 * validation-driven best-checkpoint tracking (sMAPE on the held-out window,
-  paper section 5.1).
+  paper section 5.1),
+* program spans (``repro.analysis.spans``): per (super)step a ``fit.step``
+  holding ``fit.index`` (the batch schedule to the device), ``fit.dispatch``
+  and ``fit.loss_sync`` (the host's one sync), then a ``fit.boundary``
+  holding ``fit.eval``. A step asks once whether a trace is recording.
 """
 
 from __future__ import annotations
@@ -30,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import spans
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.core import losses as L
 from repro.core.esrnn import ESRNNConfig, esrnn_forecast, esrnn_init
@@ -279,7 +284,7 @@ def train_esrnn(
 
     pre = PreemptionHandler()
     pre.install()
-    history = {"loss": [], "val_smape": [], "stragglers": []}
+    history = {"loss": [], "val_smape": []}
     ewma = None
 
     def boundary_work(reached: int, losses: np.ndarray, fused: bool) -> bool:
@@ -291,7 +296,8 @@ def train_esrnn(
         """
         history["loss"].extend(float(l) for l in losses)
         if reached % cfg.eval_every == 0 or reached == cfg.n_steps:
-            vs = float(val_smape(params))
+            with spans.span("fit.eval"):
+                vs = float(val_smape(params))
             history["val_smape"].append((reached, vs))
             if ckpt is not None:
                 ckpt.save(reached, (params, opt_state), metric=vs)
@@ -317,7 +323,6 @@ def train_esrnn(
         nonlocal ewma
         ewma = dt_per_step if ewma is None else 0.9 * ewma + 0.1 * dt_per_step
         if first_step > 5 and dt_per_step > cfg.straggler_factor * ewma:
-            history["stragglers"].append((first_step, dt_per_step, ewma))
             log.warning("straggler step %d (x%d): %.3fs/step vs ewma %.3fs",
                         first_step, k, dt_per_step, ewma)
 
@@ -341,16 +346,23 @@ def train_esrnn(
                 for step, k in segment_steps(
                         v.step, v.step + v.n_steps, cfg.scan_steps,
                         cfg.eval_every, cfg.ckpt_every):
-                    sched = jnp.asarray(v.lo + chunk_batch_schedule(
-                        v.hi - v.lo, v.batch_size, v.epoch, v.chunk_id,
-                        v.start_k + (step - v.step), k, seed=cfg.seed))
-                    t0 = time.perf_counter()
-                    params, opt_state, losses = superstep_fn(
-                        params, opt_state, sched)
-                    losses = np.asarray(losses)
-                    track_time(step, (time.perf_counter() - t0) / k, k)
-                    if boundary_work(step + k, losses, fused=True):
-                        stop = True
+                    on = spans.recording()
+                    with spans.span("fit.step", leaf=False, on=on, k=k):
+                        with spans.span("fit.index", on=on):
+                            sched = jnp.asarray(v.lo + chunk_batch_schedule(
+                                v.hi - v.lo, v.batch_size, v.epoch,
+                                v.chunk_id, v.start_k + (step - v.step), k,
+                                seed=cfg.seed))
+                        t0 = time.perf_counter()
+                        with spans.span("fit.dispatch", on=on):
+                            params, opt_state, losses = superstep_fn(
+                                params, opt_state, sched)
+                        with spans.span("fit.loss_sync", on=on):
+                            losses = np.asarray(losses)
+                        track_time(step, (time.perf_counter() - t0) / k, k)
+                    with spans.span("fit.boundary", leaf=False, on=on):
+                        stop = boundary_work(step + k, losses, fused=True)
+                    if stop:
                         break
                 if stop:
                     break
@@ -364,24 +376,40 @@ def train_esrnn(
             for step, k in segment_steps(start_step, cfg.n_steps,
                                          cfg.scan_steps, cfg.eval_every,
                                          cfg.ckpt_every):
-                sched = jnp.asarray(
-                    batch_schedule(n, bs, step, k, seed=cfg.seed))
-                t0 = time.perf_counter()
-                params, opt_state, losses = superstep_fn(
-                    params, opt_state, sched)
-                losses = np.asarray(losses)   # the one host sync per segment
-                track_time(step, (time.perf_counter() - t0) / k, k)
-                if boundary_work(step + k, losses, fused=True):
+                on = spans.recording()
+                with spans.span("fit.step", leaf=False, on=on, k=k):
+                    with spans.span("fit.index", on=on):
+                        sched = jnp.asarray(
+                            batch_schedule(n, bs, step, k, seed=cfg.seed))
+                    t0 = time.perf_counter()
+                    with spans.span("fit.dispatch", on=on):
+                        params, opt_state, losses = superstep_fn(
+                            params, opt_state, sched)
+                    with spans.span("fit.loss_sync", on=on):
+                        losses = np.asarray(losses)   # the one host sync
+                    track_time(step, (time.perf_counter() - t0) / k, k)
+                with spans.span("fit.boundary", leaf=False, on=on):
+                    stop = boundary_work(step + k, losses, fused=True)
+                if stop:
                     break
         else:
             perstep_fn = make_perstep_fn(step_fn, donate=donate)
             for step in range(start_step, cfg.n_steps):
-                idx = jnp.asarray(batch_indices(n, bs, step, seed=cfg.seed))
-                t0 = time.perf_counter()
-                params, opt_state, loss = perstep_fn(params, opt_state, idx)
-                loss_np = np.asarray(loss).reshape(1)
-                track_time(step, time.perf_counter() - t0, 1)
-                if boundary_work(step + 1, loss_np, fused=False):
+                on = spans.recording()
+                with spans.span("fit.step", leaf=False, on=on, k=1):
+                    with spans.span("fit.index", on=on):
+                        idx = jnp.asarray(
+                            batch_indices(n, bs, step, seed=cfg.seed))
+                    t0 = time.perf_counter()
+                    with spans.span("fit.dispatch", on=on):
+                        params, opt_state, loss = perstep_fn(
+                            params, opt_state, idx)
+                    with spans.span("fit.loss_sync", on=on):
+                        loss_np = np.asarray(loss).reshape(1)
+                    track_time(step, time.perf_counter() - t0, 1)
+                with spans.span("fit.boundary", leaf=False, on=on):
+                    stop = boundary_work(step + 1, loss_np, fused=False)
+                if stop:
                     break
     finally:
         pre.uninstall()
@@ -547,7 +575,7 @@ def _train_chunked(
 
     pre = PreemptionHandler()
     pre.install()
-    history = {"loss": [], "val_smape": [], "stragglers": []}
+    history = {"loss": [], "val_smape": []}
     ewma = None
     stop = False
 
@@ -555,7 +583,6 @@ def _train_chunked(
         nonlocal ewma
         ewma = dt_per_step if ewma is None else 0.9 * ewma + 0.1 * dt_per_step
         if first_step > 5 and dt_per_step > cfg.straggler_factor * ewma:
-            history["stragglers"].append((first_step, dt_per_step, ewma))
             log.warning("straggler step %d (x%d): %.3fs/step vs ewma %.3fs",
                         first_step, k, dt_per_step, ewma)
 
@@ -583,7 +610,8 @@ def _train_chunked(
             # checkpoint/eval see the chunk's latest rows through the table
             _retire(v, cparams, copt)
         if do_eval:
-            vs = streamed_val_smape()
+            with spans.span("fit.eval"):
+                vs = streamed_val_smape()
             history["val_smape"].append((reached, vs))
             if ckpt is not None:
                 ckpt.save(reached, full_state(), metric=vs,
@@ -620,15 +648,22 @@ def _train_chunked(
             for step, k in segment_steps(
                     v.step, v.step + v.n_steps, cfg.scan_steps,
                     cfg.eval_every, cfg.ckpt_every):
-                sched = jnp.asarray(chunk_batch_schedule(
-                    v.hi - v.lo, v.batch_size, v.epoch, v.chunk_id,
-                    v.start_k + (step - v.step), k, seed=cfg.seed))
-                t0 = time.perf_counter()
-                cparams, copt, losses = superstep_fn(
-                    cparams, copt, cur["y"], cur["cats"], cur["mask"], sched)
-                losses = np.asarray(losses)  # the one host sync per segment
-                track_time(step, (time.perf_counter() - t0) / k, k)
-                chunk_boundary(v, step + k, losses, cparams, copt)
+                on = spans.recording()
+                with spans.span("fit.step", leaf=False, on=on, k=k):
+                    with spans.span("fit.index", on=on):
+                        sched = jnp.asarray(chunk_batch_schedule(
+                            v.hi - v.lo, v.batch_size, v.epoch, v.chunk_id,
+                            v.start_k + (step - v.step), k, seed=cfg.seed))
+                    t0 = time.perf_counter()
+                    with spans.span("fit.dispatch", on=on):
+                        cparams, copt, losses = superstep_fn(
+                            cparams, copt, cur["y"], cur["cats"], cur["mask"],
+                            sched)
+                    with spans.span("fit.loss_sync", on=on):
+                        losses = np.asarray(losses)  # the one host sync
+                    track_time(step, (time.perf_counter() - t0) / k, k)
+                with spans.span("fit.boundary", leaf=False, on=on):
+                    chunk_boundary(v, step + k, losses, cparams, copt)
                 if stop:
                     break
             if stop:
