@@ -1,8 +1,10 @@
 """Fused scan-over-steps training engine for the ES-RNN.
 
-The per-step trainer is dispatch-bound: one jitted step per Python iteration
-plus an immediate ``float(loss)`` forces a host round-trip every step, so on
-fast hardware the device idles between launches (the BENCH_PR3 device sweep
+The per-step trainer is dispatch-bound: one jitted step per Python iteration,
+with its batch index and loss read, costs host time every step. Reading each
+loss a few steps late overlaps that host time with queued device steps, but
+a step shorter than the host path still leaves the device idle between
+launches (the BENCH_PR3 device sweep, with a host round-trip every step,
 showed 8 devices only ~1.4x faster than 1 -- overhead, not compute). This
 module removes the Python loop from the hot path the same way the paper
 removed Smyl's per-series C++ loop: compile K steps into one donated

@@ -5,25 +5,35 @@ Production posture:
   ``lax.scan`` dispatch over a precomputed on-device batch schedule
   (``repro.train.engine``); the host syncs once per superstep, which is
   where eval, checkpointing, the straggler EWMA, and hooks run,
+* per-step engine (``scan_steps == 1``): the host reads each step's loss
+  ``IN_FLIGHT`` steps late, so its index, dispatch and bookkeeping overlap
+  the device's queued steps; it drains every pending loss before eval,
+  checkpoint or preemption work, and on every step when an ``on_step`` hook
+  is present, so those see the params of the step they name,
 * checkpoint/restart (atomic, resumable mid-epoch because the batch schedule
   is stateless in ``step`` -- a resume lands on any superstep boundary and
   re-aligns with the same absolute eval/ckpt steps),
 * SIGTERM/SIGINT preemption hook -> checkpoint-and-exit (how a 1000-node job
-  survives maintenance evictions); with fused supersteps the request is
-  honored at the next superstep boundary,
+  survives maintenance evictions); the per-step engine honors the request
+  after the step being dispatched, fused supersteps at the next superstep
+  boundary,
 * straggler watchdog: wall-time EWMA per step (per-step normalized within a
-  superstep); steps slower than ``straggler_factor``x the EWMA are logged
+  superstep; in the per-step engine the interval between consecutive loss
+  reads); steps slower than ``straggler_factor``x the EWMA are logged
   (on real fleets this feeds the scheduler; here it exercises the code path),
 * validation-driven best-checkpoint tracking (sMAPE on the held-out window,
   paper section 5.1),
 * program spans (``repro.analysis.spans``): per (super)step a ``fit.step``
   holding ``fit.index`` (the batch schedule to the device), ``fit.dispatch``
-  and ``fit.loss_sync`` (the host's one sync), then a ``fit.boundary``
-  holding ``fit.eval``. A step asks once whether a trace is recording.
+  and ``fit.loss_sync`` (the host's wait for losses: in the per-step engine
+  one per step read, with id ``lag``, the number of later steps already
+  dispatched when it waited), then a ``fit.boundary`` holding ``fit.eval``.
+  A step asks once whether a trace is recording.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import logging
 import signal
@@ -45,12 +55,18 @@ from repro.data.pipeline import (
 )
 from repro.train.engine import (
     make_chunk_step_fn, make_chunk_superstep_fn, make_perstep_fn,
-    make_step_fn, make_superstep_fn, segment_steps, split_frozen,
+    make_step_fn, make_superstep_fn, next_boundary, segment_steps,
+    split_frozen,
 )
 from repro.train.host_table import HostStateTable
 from repro.train.optimizer import AdamConfig, adam_init, adam_init_sparse
 
 log = logging.getLogger("repro.train")
+
+# steps the per-step engine keeps dispatched past the one whose loss it
+# reads: one step of lead covers the host's index, dispatch and the loss's
+# D2H notification; the second absorbs host jitter (frames, GC)
+IN_FLIGHT = 2
 
 
 @dataclasses.dataclass
@@ -287,14 +303,20 @@ def train_esrnn(
     history = {"loss": [], "val_smape": []}
     ewma = None
 
-    def boundary_work(reached: int, losses: np.ndarray, fused: bool) -> bool:
+    def boundary_work(reached: int, losses, fused: bool,
+                      drained: bool = True) -> bool:
         """Host-side work at a step boundary: eval, ckpt, hooks, preemption.
 
-        ``reached`` is the number of completed steps; ``losses`` the per-step
-        losses since the previous boundary (length 1 in the per-step loop).
+        ``reached`` is the number of dispatched steps; ``losses`` the
+        per-step losses read since the previous call, in step order. Not
+        ``drained``: the per-step engine still holds losses of steps before
+        ``reached`` in flight, so only the losses are recorded and the work
+        that reads the params waits for its next drain.
         Returns True when the trainer should stop (preemption).
         """
         history["loss"].extend(float(l) for l in losses)
+        if not drained:
+            return False
         if reached % cfg.eval_every == 0 or reached == cfg.n_steps:
             with spans.span("fit.eval"):
                 vs = float(val_smape(params))
@@ -309,7 +331,7 @@ def train_esrnn(
             # hooks see one stable type); per-step engine: a float per
             # step, the pre-existing contract
             hooks["on_step"](reached - 1,
-                             losses if fused else float(losses[0]),
+                             losses if fused else float(losses[-1]),
                              params)
         if pre.requested:
             log.warning("preemption requested at step %d; checkpointing",
@@ -393,22 +415,44 @@ def train_esrnn(
                 if stop:
                     break
         else:
+            # per-step engine: read each loss IN_FLIGHT steps late, so the
+            # host's next steps queue behind the device's; drain (read every
+            # pending loss) where the params are used next: before an eval
+            # or checkpoint boundary, on a preemption request, and on every
+            # step that an on_step hook watches
             perstep_fn = make_perstep_fn(step_fn, donate=donate)
+            depth = 0 if hooks and "on_step" in hooks else IN_FLIGHT
+            pending = collections.deque()       # (step, loss) not yet read
+            t_read = 0.0
             for step in range(start_step, cfg.n_steps):
                 on = spans.recording()
                 with spans.span("fit.step", leaf=False, on=on, k=1):
                     with spans.span("fit.index", on=on):
                         idx = jnp.asarray(
                             batch_indices(n, bs, step, seed=cfg.seed))
-                    t0 = time.perf_counter()
+                    if not pending:
+                        t_read = time.perf_counter()
                     with spans.span("fit.dispatch", on=on):
                         params, opt_state, loss = perstep_fn(
                             params, opt_state, idx)
-                    with spans.span("fit.loss_sync", on=on):
-                        loss_np = np.asarray(loss).reshape(1)
-                    track_time(step, time.perf_counter() - t0, 1)
+                    if depth:
+                        loss.copy_to_host_async()
+                    pending.append((step, loss))
+                    drain = (not depth or pre.requested or step + 1 ==
+                             next_boundary(step, cfg.n_steps, cfg.eval_every,
+                                           cfg.ckpt_every))
+                    read = []
+                    while len(pending) > (0 if drain else depth):
+                        done, loss = pending.popleft()
+                        with spans.span("fit.loss_sync", on=on,
+                                        lag=len(pending)):
+                            read.append(np.asarray(loss))
+                        now = time.perf_counter()
+                        track_time(done, now - t_read, 1)
+                        t_read = now
                 with spans.span("fit.boundary", leaf=False, on=on):
-                    stop = boundary_work(step + 1, loss_np, fused=False)
+                    stop = boundary_work(step + 1, read, fused=False,
+                                         drained=drain)
                 if stop:
                     break
     finally:

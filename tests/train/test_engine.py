@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from repro.core.esrnn import make_config
-from repro.data.pipeline import prepare
+from repro.data.pipeline import batch_indices, prepare
 from repro.data.synthetic_m4 import generate
 from repro.train.engine import next_boundary, segment_steps
 from repro.train.trainer import TrainConfig, train_esrnn
@@ -125,6 +125,91 @@ def test_on_step_hook_granularity(mcfg, data):
     np.testing.assert_allclose(
         np.concatenate([np.atleast_1d(l) for _, l in fused]),
         np.asarray([l for _, l in per]), atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# per-step engine: losses read IN_FLIGHT steps late, drained where used
+# ---------------------------------------------------------------------------
+
+
+def _assert_params_equal(a, b):
+    for (pa, x), y in zip(jax.tree_util.tree_leaves_with_path(a),
+                          jax.tree_util.tree_leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=str(pa))
+
+
+def test_lagged_perstep_matches_synchronous_bitwise(mcfg, data):
+    """Reading losses late (no hook: lagged, donated) walks the trajectory
+    a hook's synchronous, undonated loop walks, to the bit. 23 steps with
+    eval every 5: the last drain is n_steps, which is no eval multiple."""
+    lagged = _fit(mcfg, data, 23, eval_every=5)
+    sync = _fit(mcfg, data, 23, eval_every=5,
+                hooks={"on_step": lambda s, l, p: None})
+    assert len(lagged["history"]["loss"]) == 23
+    assert lagged["history"]["loss"] == sync["history"]["loss"]
+    assert [s for s, _ in lagged["history"]["val_smape"]] \
+        == [5, 10, 15, 20, 23]
+    assert lagged["history"]["val_smape"] == sync["history"]["val_smape"]
+    _assert_params_equal(lagged["params"], sync["params"])
+
+
+def test_preemption_mid_segment_checkpoints_the_step_it_names(
+        mcfg, data, tmp_path, monkeypatch):
+    """A request seen while step 7 is prepared (between the evals at 5 and
+    10) stops the run after step 7 with a checkpoint of step 8 holding the
+    params of an uninterrupted 8-step run, not of a later step."""
+    from repro.checkpoint.checkpointer import Checkpointer
+    from repro.train import trainer
+
+    handlers = []
+
+    class Handler(trainer.PreemptionHandler):
+        def __init__(self):
+            super().__init__()
+            handlers.append(self)
+
+    def indices(n, bs, step, *, seed):
+        if step == 7:
+            handlers[-1].requested = True
+        return batch_indices(n, bs, step, seed=seed)
+
+    ref = _fit(mcfg, data, 8, eval_every=5)
+    monkeypatch.setattr(trainer, "PreemptionHandler", Handler)
+    monkeypatch.setattr(trainer, "batch_indices", indices)
+    d = str(tmp_path / "preempt")
+    out = _fit(mcfg, data, 23, eval_every=5, ckpt_dir=d)
+    assert out["history"]["loss"] == ref["history"]["loss"]
+    assert Checkpointer(d).latest_step() == 8
+    step, (params, _) = Checkpointer(d).restore(
+        (out["params"], out["opt_state"]))
+    assert step == 8
+    _assert_params_equal(ref["params"], params)
+    _assert_params_equal(ref["params"], out["params"])
+
+
+def test_traced_perstep_fit_reads_each_loss_once_with_its_lag(
+        mcfg, data, tmp_path):
+    """One ``fit.loss_sync`` per step, each with ``lag``: 2 outside drains,
+    counting down to 0 as a drain reads every pending loss (at the evals
+    after steps 5 and 10 and at n_steps 12)."""
+    from bench import spans as bench_spans
+    from repro.analysis import spans
+    from repro.train.trainer import IN_FLIGHT
+
+    _fit(mcfg, data, 12, eval_every=5)          # compiles step and eval
+    spans.reset()
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            _fit(mcfg, data, 12, eval_every=5)
+        s = spans.summary()
+    finally:
+        spans.reset()
+    assert IN_FLIGHT == 2
+    assert s["fit.loss_sync"].count == 12
+    assert [i["lag"] for i in s["fit.loss_sync"].ids] \
+        == [2, 2, 2, 1, 0, 2, 2, 2, 1, 0, 1, 0]
+    assert bench_spans.fit_steps(s) == 12
 
 
 # ---------------------------------------------------------------------------
