@@ -18,9 +18,8 @@ import time
 
 import numpy as np
 
-from bench import compare, flops, harness, sut, weights
+from bench import compare, harness, sut
 from bench.fleet import build_fleet
-from bench.reference import esrnn as ref
 
 CHECK_STEPS = 3
 
@@ -66,14 +65,15 @@ def run(cell, spec_overrides=None) -> harness.Outcome:
     from repro.forecast import ESRNNForecaster
 
     cfg, mix = cell.config, cell.mix
+    ref, adapter = cell.reference, cell.adapter
     batch, eval_every = mix["batch_size"], mix["eval_every"]
     fleet = build_fleet(cfg)
-    w0 = weights.init_weights(cfg, fleet.n_series, cell.seed)
+    w0 = ref.init_weights(cfg, fleet.n_series, cell.seed)
     sched_seed = harness.sub_seed(cell.seed, 3)
-    spec = sut.make_spec(cfg, batch_size=batch, eval_every=eval_every,
-                         seed=sched_seed, **(spec_overrides or {}))
-    data = sut.program_data(cfg, fleet)
-    p0 = sut.program_params(cfg, w0)
+    spec = adapter.make_spec(cfg, batch_size=batch, eval_every=eval_every,
+                             seed=sched_seed, **(spec_overrides or {}))
+    data = adapter.program_data(cfg, fleet)
+    p0 = adapter.program_params(cfg, w0)
     f = ESRNNForecaster(spec)
 
     t_made = time.perf_counter()
@@ -82,14 +82,14 @@ def run(cell, spec_overrides=None) -> harness.Outcome:
         t_first = _fit(f, data, 1)
     b1 = cfg["adam_b1"]
     prog_grad = {k: v / (1.0 - b1) for k, v in compare.norms(
-        sut.program_leaves(states[0]["mu"])).items()}
+        adapter.program_leaves(states[0]["mu"])).items()}
     del states
     f.params_ = p0
     t_check = _fit(f, data, CHECK_STEPS)
     prog_losses = list(f.history_["loss"])
-    p0_leaves = sut.program_leaves(p0)
+    p0_leaves = adapter.program_leaves(p0)
     prog_change = compare.norms({k: v - p0_leaves[k] for k, v in
-                                 sut.program_leaves(f.params_).items()})
+                                 adapter.program_leaves(f.params_).items()})
     t_probe = _fit(f, data, mix["probe_steps"])
     rate = step_rate(mix["probe_steps"], t_probe, t_check)
     # what a call costs besides its steps (re-trace, its last eval)
@@ -109,10 +109,10 @@ def run(cell, spec_overrides=None) -> harness.Outcome:
                       for k in range(CHECK_STEPS)])
     ref_losses, ref_g1, ref_w = ref.train(cell.model, w0, fleet.train,
                                           fleet.cats, sched)
-    ref_grad = compare.norms(weights.leaves(ref_g1))
-    w0_leaves = weights.leaves(w0)
+    ref_grad = compare.norms(ref.leaves(ref_g1))
+    w0_leaves = ref.leaves(w0)
     ref_change = compare.norms({k: v - w0_leaves[k] for k, v in
-                                weights.leaves(ref_w).items()})
+                                ref.leaves(ref_w).items()})
     numbers = {
         "grad_gap": compare.leaf_gap(prog_grad, ref_grad),
         "update_gap": compare.leaf_gap(prog_change, ref_change,
@@ -120,8 +120,8 @@ def run(cell, spec_overrides=None) -> harness.Outcome:
     }
     n_evals = -(-n_steps // eval_every)
     t_len = fleet.train.shape[1]
-    work = flops.train_step_flops(cell.model, batch, t_len) * n_steps + \
-        flops.forecast_flops(cell.model, fleet.n_series, t_len) * n_evals
+    work = ref.train_step_flops(cell.model, batch, t_len) * n_steps + \
+        ref.forecast_flops(cell.model, fleet.n_series, t_len) * n_evals
     return harness.Outcome(
         attempted=n_steps, failed=failed, numbers=numbers,
         metrics={"fit_series_per_s": n_steps * batch / wall},

@@ -17,19 +17,19 @@ import time
 
 import numpy as np
 
-from bench import compare, flops, harness, sut, weights
+from bench import compare, harness
 from bench.fleet import build_fleet
-from bench.reference import esrnn as ref
 
 
 def run(cell, spec_overrides=None) -> harness.Outcome:
     from repro.forecast import ESRNNForecaster
 
     cfg, mix = cell.config, cell.mix
+    ref, adapter = cell.reference, cell.adapter
     fleet = build_fleet(cfg)
-    w0 = weights.init_weights(cfg, fleet.n_series, cell.seed)
-    f = ESRNNForecaster(sut.make_spec(cfg, **(spec_overrides or {})))
-    f.params_ = sut.program_params(cfg, w0)
+    w0 = ref.init_weights(cfg, fleet.n_series, cell.seed)
+    f = ESRNNForecaster(adapter.make_spec(cfg, **(spec_overrides or {})))
+    f.params_ = adapter.program_params(cfg, w0)
     f.n_series_ = fleet.n_series
     y, cats = getattr(fleet, mix["history"]), fleet.cats
     for _ in range(2):
@@ -62,7 +62,7 @@ def run(cell, spec_overrides=None) -> harness.Outcome:
         metrics={"predict_series_per_s": calls * n / wall},
         memory_peak_bytes=peak,
         work={"calls": calls, "window_s": wall,
-              "flops": calls * flops.forecast_flops(cell.model, n,
-                                                    y.shape[1])},
+              "flops": calls * ref.forecast_flops(cell.model, n,
+                                                  y.shape[1])},
         notes=[f"predict: {n} series x T={y.shape[1]}, {calls} calls in "
                f"{wall:.3f} s, {len(kept)} calls' answers compared"])

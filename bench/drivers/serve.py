@@ -26,26 +26,26 @@ import time
 
 import numpy as np
 
-from bench import compare, harness, openloop, sut, weights
-from bench.reference import esrnn as ref
+from bench import compare, harness, openloop, sut
 
 WAIT_AFTER_CLOSE_S = 60.0
 
 
 def _reference(cell, w0, reqs, buckets):
     """The reference's forecast of every request, grouped by length bucket."""
+    ref = cell.reference
     hw0 = {k: np.asarray(v) for k, v in w0["hw"].items()}
     out = np.empty((len(reqs), cell.config["output_size"]), np.float64)
     groups = {}
     for i, r in enumerate(reqs):
-        groups.setdefault(ref.length_bucket(len(r.y), buckets), []).append(i)
+        groups.setdefault(openloop.length_bucket(len(r.y), buckets), []).append(i)
     for bucket, idx in groups.items():
         ids = np.array([reqs[i].series_id for i in idx])
         known = ids >= 0
         rows = {k: np.where(known.reshape((-1,) + (1,) * (v.ndim - 1)),
                             v[np.maximum(ids, 0)], ref.PRIMER_HW[k])
                 for k, v in hw0.items()}
-        y = np.stack([ref.shape_history(reqs[i].y, bucket) for i in idx])
+        y = np.stack([openloop.shape_history(reqs[i].y, bucket) for i in idx])
         cats = np.zeros((len(idx), cell.config["n_categories"]), np.float32)
         cats[np.arange(len(idx)), [reqs[i].category for i in idx]] = 1.0
         out[idx] = ref.forecast(cell.model, {**w0, "hw": rows}, y, cats,
@@ -58,9 +58,9 @@ def run(cell, spec_overrides=None) -> harness.Outcome:
     from repro.forecast.server import ServerConfig
 
     cfg, mix = cell.config, cell.mix
-    w0 = weights.init_weights(cfg, cfg["n_series"], cell.seed)
-    f = ESRNNForecaster(sut.make_spec(cfg, **(spec_overrides or {})))
-    f.params_ = sut.program_params(cfg, w0)
+    w0 = cell.reference.init_weights(cfg, cfg["n_series"], cell.seed)
+    f = ESRNNForecaster(cell.adapter.make_spec(cfg, **(spec_overrides or {})))
+    f.params_ = cell.adapter.program_params(cfg, w0)
     f.n_series_ = cfg["n_series"]
     srv = f.serve(server_config=ServerConfig(),
                   length_buckets=tuple(mix["length_buckets"]),
@@ -76,7 +76,7 @@ def run(cell, spec_overrides=None) -> harness.Outcome:
                                else r.series_id)
 
     for bucket in buckets:
-        pool = [r for r in reqs if ref.length_bucket(len(r.y), buckets)
+        pool = [r for r in reqs if openloop.length_bucket(len(r.y), buckets)
                 == bucket] or reqs
         for bb in mix["batch_buckets"]:
             srv.dispatcher.run_bucket(
@@ -104,8 +104,9 @@ def run(cell, spec_overrides=None) -> harness.Outcome:
                     late[i] = time.perf_counter() - due
                     done.register(srv.submit(program_reqs[i]), i)
                 close = t0 + cell.window_seconds
-                if close > time.perf_counter():
-                    time.sleep(close - time.perf_counter())
+                wait = close - time.perf_counter()
+                if wait > 0:
+                    time.sleep(wait)
             done.wait(n, close + WAIT_AFTER_CLOSE_S)
         finally:
             srv.stop(drain=False)

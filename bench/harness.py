@@ -4,9 +4,15 @@ A cell (``workloads`` in BENCHMARK.json) names a configuration and a
 traffic mix. Each is a data file found by name: ``bench/configs/<config>.json``
 and ``bench/traffic/<traffic>.json``; the mix names the driver that runs it
 (``bench/drivers/<driver>.py``), and the limits of the numbers that decide
-``correct`` are in ``bench/limits/<workload>.json``. Each per-layer metric is
-a reader of its own, ``bench/metrics/<metric>.py``, with a function
-``read(ctx)`` that returns a number, or None where it finds nothing to read.
+``correct`` are in ``bench/limits/<workload>.json``. The configuration names
+its model ``family``, whose plain side is ``bench/reference/<family>.py``
+(``MODEL_KEYS``, ``init_weights``, ``leaves``, ``train``, ``forecast``,
+``PRIMER_HW``, ``train_step_flops``, ``forecast_flops``) and whose program
+side is ``bench/adapters/<family>.py`` (``make_spec``, ``program_params``,
+``program_leaves``, ``program_data``); the drivers are shared. Each
+per-layer metric is a reader of its own, ``bench/metrics/<metric>.py``,
+with a function ``read(ctx)`` that returns a number, or None where it finds
+nothing to read.
 
 A driver gets a :class:`Cell` and returns an :class:`Outcome`. It makes its
 data and weights from the seed, warms up every shape its window uses, runs
@@ -27,6 +33,7 @@ import shutil
 import threading
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -40,14 +47,14 @@ TRACE_DIR = ROOT / "bench" / ".traces"
 # mix may set ``trace_seconds``): the trace of a whole window is too large to
 # read within a run's time
 TRACE_SECONDS = 1.0
-# model keys the plain reference reads (flat, hashable)
-MODEL_KEYS = ("seasonality", "input_size", "output_size", "hidden_size",
-              "dilations", "n_categories", "tau", "rnn_lr", "hw_lr",
-              "clip_norm", "adam_b1", "adam_b2", "adam_eps")
 
 
 class NoChip(RuntimeError):
     """JAX found no accelerator, or fewer chips than the cell asks for."""
+
+
+class UnknownFamily(LookupError):
+    """A configuration names no model family, or one without its files."""
 
 
 def sub_seed(seed: int, purpose: int) -> int:
@@ -77,6 +84,8 @@ class Cell:
     config: dict
     mix: dict
     limits: dict
+    reference: ModuleType              # bench/reference/<family>.py
+    adapter: ModuleType                # bench/adapters/<family>.py
     seed: int
     seconds: float
     trace: bool
@@ -92,7 +101,7 @@ class Cell:
 
     @property
     def model(self) -> dict:
-        return {k: self.config[k] for k in MODEL_KEYS}
+        return {k: self.config[k] for k in self.reference.MODEL_KEYS}
 
     @contextlib.contextmanager
     def window(self):
@@ -160,8 +169,13 @@ def load_cell(root: Path, workload: str, *, seed: int, seconds: float,
     mix = _json(root / "bench" / "traffic" / f"{wl['traffic']}.json")
     limits_path = root / "bench" / "limits" / f"{workload}.json"
     limits = _json(limits_path) if limits_path.exists() else {}
-    return Cell(workload=wl, config=_json(root / cfg_file), mix=mix,
-                limits=limits, seed=seed, seconds=seconds, trace=trace,
+    config = _json(root / cfg_file)
+    if "family" not in config:
+        raise UnknownFamily(f"{cfg_file} states no \"family\"")
+    return Cell(workload=wl, config=config, mix=mix, limits=limits,
+                reference=family_module(root, "reference", config["family"]),
+                adapter=family_module(root, "adapters", config["family"]),
+                seed=seed, seconds=seconds, trace=trace,
                 trace_dir=root / "bench" / ".traces" / workload)
 
 
@@ -169,14 +183,30 @@ def driver(name: str):
     return importlib.import_module(f"bench.drivers.{name}")
 
 
+def _load(name: str, path: Path) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family_module(root: Path, part: str, family: str) -> ModuleType:
+    """``bench/<part>/<family>.py`` of the checkout at ``root``: a model
+    family's ``reference`` or its ``adapters`` module. This checkout's own
+    files are imported as the package's modules, others by their path."""
+    path = root / "bench" / part / f"{family}.py"
+    if not path.is_file():
+        raise UnknownFamily(
+            f"model family {family!r} has no {path.relative_to(root)}")
+    if path.resolve() == ROOT / "bench" / part / f"{family}.py":
+        return importlib.import_module(f"bench.{part}.{family}")
+    return _load(f"bench_{part}_{family}", path)
+
+
 def reader(root: Path, metric: str):
     """The ``read`` function of ``bench/metrics/<metric>.py``."""
     path = root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load("bench_metric_" + metric.replace(".", "_"), path).read
 
 
 def metrics_of(bench: dict, section: str, workload: str) -> List[dict]:

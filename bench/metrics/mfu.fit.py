@@ -1,9 +1,10 @@
 """The fit window's share of the chip's bf16 matrix peak, in %.
 
 The operations are those the algorithm requires for the window's fit call
-(``bench/flops.py``: every step's forward and backward pass and every eval
-forecast), over the call's wall time. A default-precision float32 dot is
-one bf16 pass on the MXU, so the bf16 peak is the divisor.
+(``train_step_flops`` and ``forecast_flops`` of the configuration's family,
+``bench/reference/<family>.py``: every step's forward and backward pass and
+every eval forecast), over the call's wall time. A default-precision
+float32 dot is one bf16 pass on the MXU, so the bf16 peak is the divisor.
 """
 
 

@@ -1,7 +1,8 @@
 """The predict window's share of the chip's bf16 matrix peak, in %.
 
-The operations are those the algorithm requires (``bench/flops.py``: one
-Eq.-5 forecast of every series per call), over the window's wall time. A
+The operations are those the algorithm requires (``forecast_flops`` of the
+configuration's family, ``bench/reference/<family>.py``: one Eq.-5 forecast
+of every series per call), over the window's wall time. A
 default-precision float32 dot is one bf16 pass on the MXU, so the bf16 peak
 is the divisor.
 """

@@ -13,7 +13,9 @@ work:
   the known share is exact and the ids are uniform over the fitted fleet.
 
 Each request is timed from its due time (``arrival``), not from when the
-generator got round to submitting it.
+generator got round to submitting it. ``length_bucket`` and
+``shape_history`` state how the server shapes a request's history, which
+the serve driver's reference follows.
 """
 
 from __future__ import annotations
@@ -75,3 +77,20 @@ def make_requests(mix: dict, config: dict, *, seconds: float,
     ys = histories(rng, lens, config["seasonality"])
     return [Request(float(a), y, int(c), int(i))
             for a, y, c, i in zip(arrivals, ys, cats, ids)]
+
+
+def length_bucket(n_obs: int, buckets) -> int:
+    """The first bucket at or above ``n_obs``, else the largest."""
+    for b in buckets:
+        if n_obs <= b:
+            return b
+    return buckets[-1]
+
+
+def shape_history(y: np.ndarray, bucket: int) -> np.ndarray:
+    """Left-pad with the first value up to ``bucket``, or keep the last
+    ``bucket`` values."""
+    y = np.asarray(y, np.float32)
+    if len(y) >= bucket:
+        return y[-bucket:]
+    return np.concatenate([np.full(bucket - len(y), y[0], np.float32), y])

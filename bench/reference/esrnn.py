@@ -1,4 +1,5 @@
-"""A plain float32 ES-RNN in jax.numpy: the yardstick for every cell.
+"""The ``esrnn`` family's plain side: a float32 ES-RNN in jax.numpy, its
+weights made from a seed, and the operations it requires.
 
 It follows the paper (Redd et al. 2019, secs. 3.1-3.5; Smyl's ES-RNN) and
 imports nothing of the program. Every dot runs at ``Precision.HIGHEST``, so
@@ -22,19 +23,54 @@ on a TPU it multiplies in float32 and not in one bfloat16 pass.
   gradient is clipped to a global norm and applied by Adam with two
   learning rates (per-series rows, shared weights) over the whole table.
 
-Where a cell serves requests, ``shape_history`` and ``PRIMER_HW`` state
-the server's documented rules: a history is left-padded with its first
-value up to its length bucket, or keeps its last ``max(bucket)`` values,
-and a series the fit never saw uses alpha = gamma = 0.5 and a flat season.
+Where a cell serves requests, ``PRIMER_HW`` states the server's documented
+row for a series the fit never saw: alpha = gamma = 0.5 and a flat season.
+
+The weights (``init_weights``) are random, made on the device from a seed
+in one jitted call, in the layout that the family's adapter
+(``bench/adapters/esrnn.py``) hands to the program:
+
+    {"hw":    {"alpha_logit": (N,), "gamma_logit": (N,), "seas_logit": (N, m)},
+     "lstm":  [{"wx": (I, 4H), "wh": (H, 4H), "b": (4H,)}, ...]  # one per layer,
+                                                                # blocks in order
+     "dense_w": (H, H), "dense_b": (H,), "out_w": (H, O), "out_b": (O,)}
+
+LSTM gates are laid out (i, f, g, o) along the 4H axis. Weight matrices
+are uniform in +-1/sqrt(fan_in); biases and the Holt-Winters logits are
+small and random, so every term of the model moves the result.
+
+The operation counts (``train_step_flops``, ``forecast_flops``) are of
+matrix-multiply work only: the LSTM gate products (``x W_x + h W_h``) of
+every layer and the readout's dense and output products, two operations
+per multiply-add. The Holt-Winters recurrence, the Eq.-6 window
+arithmetic, the gate nonlinearities and the loss are elementwise work on
+the vector unit and are left out, so a share of the chip's matrix peak is
+never overstated. Only what a result needs is counted:
+
+* a training step needs the network at every window position that has at
+  least one target inside the series (positions W-1 .. T-2: the last
+  position has none), forward and backward; backward counts twice forward;
+* a forecast needs only the last position's readout, and of each LSTM
+  layer only the positions that the last position reaches through its
+  dilations (a layer of dilation d at position t needs t-d, t-2d, ...);
+* padding that an implementation adds (a dilation's ragged tail, a batch
+  bucket's repeated rows, a length bucket's left pad) is not counted.
 """
 
 from __future__ import annotations
 
 from functools import partial
+from typing import Iterable, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+# the configuration keys that ``train`` and ``forecast`` read (flat,
+# hashable): a cell's ``model``
+MODEL_KEYS = ("seasonality", "input_size", "output_size", "hidden_size",
+              "dilations", "n_categories", "tau", "rnn_lr", "hw_lr",
+              "clip_norm", "adam_b1", "adam_b2", "adam_eps")
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -225,17 +261,126 @@ def train(cfg, w, y, cats, schedule):
 PRIMER_HW = {"alpha_logit": 0.0, "gamma_logit": 0.0, "seas_logit": 0.0}
 
 
-def length_bucket(n_obs: int, buckets) -> int:
-    for b in buckets:
-        if n_obs <= b:
-            return b
-    return buckets[-1]
+# weights
 
 
-def shape_history(y: np.ndarray, bucket: int) -> np.ndarray:
-    """Left-pad with the first value up to ``bucket``, or keep the last
-    ``bucket`` values."""
-    y = np.asarray(y, np.float32)
-    if len(y) >= bucket:
-        return y[-bucket:]
-    return np.concatenate([np.full(bucket - len(y), y[0], np.float32), y])
+def n_layers(cfg) -> int:
+    return sum(len(block) for block in cfg["dilations"])
+
+
+def key_from_seed(seed: int, purpose: int):
+    """A JAX key for one purpose, for any non-negative seed (64-bit too)."""
+    words = np.random.SeedSequence([seed, purpose]).generate_state(2)
+    return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32))
+
+
+@partial(jax.jit, static_argnames=("n_series", "m", "inp", "hid", "out",
+                                   "layers"))
+def _init(key, *, n_series, m, inp, hid, out, layers):
+    keys = iter(jax.random.split(key, 8 + 3 * layers))
+
+    def unif(shape, fan_in):
+        return jax.random.uniform(next(keys), shape, jnp.float32, -1.0,
+                                  1.0) / jnp.sqrt(float(fan_in))
+
+    def normal(shape, scale):
+        return scale * jax.random.normal(next(keys), shape, jnp.float32)
+
+    hw = {"alpha_logit": normal((n_series,), 0.5),
+          "gamma_logit": normal((n_series,), 0.5),
+          "seas_logit": normal((n_series, m), 0.05)}
+    lstm = []
+    for layer in range(layers):
+        fan = inp if layer == 0 else hid
+        lstm.append({"wx": unif((fan, 4 * hid), fan),
+                     "wh": unif((hid, 4 * hid), hid),
+                     "b": normal((4 * hid,), 0.1)})
+    return {"hw": hw, "lstm": lstm,
+            "dense_w": unif((hid, hid), hid), "dense_b": normal((hid,), 0.1),
+            "out_w": unif((hid, out), hid), "out_b": normal((out,), 0.1)}
+
+
+def init_weights(cfg, n_series: int, seed: int):
+    """Weights for ``n_series`` series of configuration ``cfg`` (a dict)."""
+    return _init(key_from_seed(seed, 1), n_series=n_series,
+                 m=cfg["seasonality"],
+                 inp=cfg["input_size"] + cfg["n_categories"],
+                 hid=cfg["hidden_size"], out=cfg["output_size"],
+                 layers=n_layers(cfg))
+
+
+def leaves(w) -> dict:
+    """Flat ``{name: array}`` view of a weights tree, in a fixed order."""
+    out = {f"hw.{k}": w["hw"][k] for k in ("alpha_logit", "gamma_logit",
+                                           "seas_logit")}
+    for i, layer in enumerate(w["lstm"]):
+        for k in ("wx", "wh", "b"):
+            out[f"lstm{i}.{k}"] = layer[k]
+    for k in ("dense_w", "dense_b", "out_w", "out_b"):
+        out[k] = w[k]
+    return out
+
+
+# operation counts
+
+
+def _layers(cfg) -> List[tuple]:
+    """(input width, dilation) of every LSTM layer, blocks in order."""
+    out, width = [], cfg["input_size"] + cfg["n_categories"]
+    for block in cfg["dilations"]:
+        for d in block:
+            out.append((width, int(d)))
+            width = cfg["hidden_size"]
+    return out
+
+
+def cell_flops(cfg, width: int) -> int:
+    """One LSTM cell at one position: gates of width 4H from [x, h]."""
+    h = cfg["hidden_size"]
+    return 2 * (width + h) * 4 * h
+
+
+def readout_flops(cfg) -> int:
+    h = cfg["hidden_size"]
+    return 2 * h * h + 2 * h * cfg["output_size"]
+
+
+def position_flops(cfg) -> int:
+    """All LSTM layers plus the readout, at one position of one series."""
+    return sum(cell_flops(cfg, w) for w, _ in _layers(cfg)) + readout_flops(cfg)
+
+
+def train_step_flops(cfg, batch: int, t_len: int) -> int:
+    """Forward and backward of one step on ``batch`` series of length T."""
+    positions = t_len - cfg["input_size"]          # W-1 .. T-2
+    return 3 * batch * positions * position_flops(cfg)
+
+
+def _needed(positions: Iterable[int], d: int) -> set:
+    """Positions a dilation-d chain must compute to reach ``positions``."""
+    out = set()
+    for p in positions:
+        while p >= 0 and p not in out:
+            out.add(p)
+            p -= d
+    return out
+
+
+def forecast_flops(cfg, n_series: int, t_len: int) -> int:
+    """One Eq.-5 forecast from the end of each of ``n_series`` histories."""
+    last = t_len - cfg["input_size"]               # index of position T-1
+    need, total = {last}, 0
+    blocks = []
+    layers = iter(_layers(cfg))
+    for block in cfg["dilations"]:
+        blocks.append([next(layers) for _ in block])
+    # walk from the output back to the input: each layer computes the
+    # closure of what the layer above reads (its output positions, and for
+    # a residual block the block input at the block's output positions)
+    for block in reversed(blocks):
+        out_need = need
+        for width, d in reversed(block):
+            need = _needed(need, d)
+            total += len(need) * cell_flops(cfg, width)
+        need = need | out_need if block is not blocks[0] else need
+    return n_series * (total + readout_flops(cfg))

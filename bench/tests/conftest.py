@@ -1,11 +1,14 @@
 """Cells of the benchmark at a size a CPU test run can hold.
 
 The widths stay those of the configurations; only the fleet, the batch,
-the eval period, the request rate and the window shrink.
+the eval period, the request rate and the window shrink, as each cell's
+``bench/tests/small/<cell>.json`` states (``config`` and ``mix`` keys laid
+over the cell's own).
 """
 
 from __future__ import annotations
 
+import json
 import time
 from pathlib import Path
 
@@ -14,25 +17,25 @@ from bench.fleet import generate, prepare
 
 ROOT = Path(__file__).resolve().parents[2]
 
-SMALL = {
-    "quarterly-fit": ({"data_scale": 0.03},
-                      {"batch_size": 32, "probe_steps": 13, "eval_every": 5}),
-    "monthly-fit": ({"data_scale": 0.01},
-                    {"batch_size": 32, "probe_steps": 13, "eval_every": 5}),
-    "monthly-predict": ({"data_scale": 0.01},
-                        {"keep_share": 0.5, "reference_block": 128}),
-    "quarterly-serve": ({"n_series": 100},
-                        {"rate_per_s": 40, "reference_block": 128}),
-}
+
+def cells(*drivers: str) -> list:
+    """The cells of BENCHMARK.json whose traffic mix runs one of
+    ``drivers``, in the file's order."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    return [w["name"] for w in bench["workloads"]
+            if json.loads((ROOT / "bench" / "traffic" / f"{w['traffic']}.json")
+                          .read_text())["driver"] in drivers]
 
 
 def small_cell(workload: str, *, seed: int = 2**31 + 11,
-               seconds: float = 0.5) -> harness.Cell:
-    cell = harness.load_cell(ROOT, workload, seed=seed, seconds=seconds,
+               seconds: float = 0.5, root: Path = ROOT) -> harness.Cell:
+    cell = harness.load_cell(root, workload, seed=seed, seconds=seconds,
                              trace=False)
-    cfg, mix = SMALL[workload]
+    small = json.loads((root / "bench" / "tests" / "small" /
+                        f"{workload}.json").read_text())
+    cfg = small["config"]
     cell.config = {**cell.config, **cfg}
-    cell.mix = {**cell.mix, **mix}
+    cell.mix = {**cell.mix, **small["mix"]}
     if "data_scale" in cfg:
         c = cell.config
         c["n_series"] = prepare(
@@ -42,10 +45,10 @@ def small_cell(workload: str, *, seed: int = 2**31 + 11,
     return cell
 
 
-def run_small(workload: str, **kw) -> dict:
+def run_small(workload: str, *, root: Path = ROOT, **kw) -> dict:
     """A whole run of a small cell on the CPU: the harness minus its look
     for a chip."""
-    cell = small_cell(workload, **kw)
-    return harness.run(ROOT, workload, seed=cell.seed, seconds=cell.seconds,
+    cell = small_cell(workload, root=root, **kw)
+    return harness.run(root, workload, seed=cell.seed, seconds=cell.seconds,
                        trace=False, t_start=time.perf_counter(),
                        require_chip=False, cell=cell, log=lambda s: None)

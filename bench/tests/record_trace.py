@@ -23,14 +23,14 @@ def main(out_dir: str) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import trace, weights
+    from bench import trace
     from bench.reference import esrnn as ref
 
     cfg = json.load(open(os.path.join(ROOT, "bench", "configs",
                                       "esrnn-quarterly.json")))
     model = {k: cfg[k] for k in ("seasonality", "input_size", "output_size",
                                  "hidden_size", "dilations", "n_categories")}
-    w = weights.init_weights(cfg, 64, 0)
+    w = ref.init_weights(cfg, 64, 0)
     y = np.exp(np.random.default_rng(0).normal(5, 0.1, (64, 40))).astype(
         np.float32)
     cats = np.eye(6, dtype=np.float32)[np.arange(64) % 6]

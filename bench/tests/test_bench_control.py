@@ -1,5 +1,5 @@
 """The control, the step below the precision that a configuration states,
-comes out not correct in every cell.
+comes out not correct in every cell of BENCHMARK.json.
 
 A configuration names its control: for the fit cells' float32 at matmul
 precision ``highest``, the precision ``high`` (three bf16 passes); for the
@@ -17,11 +17,10 @@ import pytest
 from bench import harness
 from bench.compare import judge
 
-from .conftest import small_cell
+from .conftest import cells, small_cell
 
 
-@pytest.mark.parametrize("workload", ["quarterly-fit", "monthly-fit",
-                                      "quarterly-serve", "monthly-predict"])
+@pytest.mark.parametrize("workload", cells("fit", "predict", "serve"))
 def test_control_is_not_correct(workload):
     cell = small_cell(workload)
     cell.t_start = time.perf_counter()

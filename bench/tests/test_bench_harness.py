@@ -1,6 +1,8 @@
-"""The harness finds cells, mixes and metric readers by name, holds
-BENCHMARK.json to its contract, and refuses to run without a chip."""
+"""The harness finds cells, mixes, model families and metric readers by
+name, holds BENCHMARK.json to its contract, and refuses to run without a
+chip."""
 
+import hashlib
 import json
 import os
 import re
@@ -12,7 +14,7 @@ import pytest
 
 from bench import harness
 
-from .conftest import ROOT, run_small
+from .conftest import ROOT, run_small, small_cell
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -40,6 +42,9 @@ def test_benchmark_json_keeps_its_contract():
         stated = json.loads((ROOT / c["file"]).read_text())
         assert stated["matmul_precision"] in ("default", "highest")
         assert set(stated["control"]) <= {"precision", "matmul_precision"}
+        for part in ("reference", "adapters"):
+            assert (ROOT / "bench" / part /
+                    f"{stated['family']}.py").is_file()
         names.add(c["name"])
     cells = set()
     for w in b["workloads"]:
@@ -48,6 +53,8 @@ def test_benchmark_json_keeps_its_contract():
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
         assert (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").exists()
         assert (ROOT / "bench" / "limits" / f"{w['name']}.json").exists()
+        assert (ROOT / "bench" / "tests" / "small" /
+                f"{w['name']}.json").exists()
         cells.add(w["name"])
     assert len(cells) == len(b["workloads"])
     e2e = {m["name"]: m for m in b["end_to_end"]}
@@ -76,10 +83,20 @@ def test_benchmark_json_keeps_its_contract():
     assert runs * (b["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
 
 
-def test_a_new_mix_and_metric_are_found_by_name(tmp_path):
+def _copy_bench(tmp_path):
     shutil.copytree(ROOT / "bench", tmp_path / "bench",
                     ignore=shutil.ignore_patterns(".jax_cache", ".traces",
                                                   "__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+
+
+def _digests(root):
+    return {p: hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in sorted(root.rglob("*")) if p.is_file()}
+
+
+def test_a_new_mix_and_metric_are_found_by_name(tmp_path):
+    _copy_bench(tmp_path)
     b = bench()
     b["workloads"].append({"name": "dummy-cell", "config": "esrnn-monthly",
                            "traffic": "dummy_mix", "chips": 1,
@@ -109,6 +126,87 @@ def test_a_new_mix_and_metric_are_found_by_name(tmp_path):
         {"work": {"window_s": 0.5}}) == 500.0
     assert [m["name"] for m in harness.metrics_of(
         b, "end_to_end", "dummy-cell")] == ["setup_s", "predict_series_per_s"]
+
+
+# a family of new files only: esrnn's model under other leaf names
+TOY_REFERENCE = """from bench.reference import esrnn
+from bench.reference.esrnn import (MODEL_KEYS, PRIMER_HW, forecast,
+                                   forecast_flops, init_weights, train,
+                                   train_step_flops)
+
+
+def leaves(w):
+    return {"toy." + k: v for k, v in esrnn.leaves(w).items()}
+"""
+TOY_ADAPTER = """from bench.adapters import esrnn
+from bench.adapters.esrnn import make_spec, program_data, program_params
+
+
+def program_leaves(tree):
+    return {"toy." + k: v for k, v in esrnn.program_leaves(tree).items()}
+"""
+
+
+def test_a_new_family_enters_as_new_files_only(tmp_path):
+    _copy_bench(tmp_path)
+    before = _digests(tmp_path / "bench")
+    b = bench()
+    old = {k: list(b[k]) for k in ("configs", "workloads")}
+    cfg = json.loads((ROOT / "bench/configs/esrnn-quarterly-highest.json")
+                     .read_text())
+    (tmp_path / "bench/configs/toy.json").write_text(json.dumps(
+        {**cfg, "name": "toy", "family": "toy"}))
+    (tmp_path / "bench/reference/toy.py").write_text(TOY_REFERENCE)
+    (tmp_path / "bench/adapters/toy.py").write_text(TOY_ADAPTER)
+    b["configs"].append({"name": "toy", "source": "a test",
+                         "file": "bench/configs/toy.json", "reduced": [],
+                         "why": "a test"})
+    for cell, traffic, like in [("toy-fit", "fit_b256", "quarterly-fit"),
+                                ("toy-predict", "predict_fleet",
+                                 "monthly-predict")]:
+        b["workloads"].append({"name": cell, "config": "toy",
+                               "traffic": traffic, "chips": 1,
+                               "why": "a test"})
+        for part in ("limits", "tests/small"):
+            (tmp_path / "bench" / part / f"{cell}.json").write_text(
+                (ROOT / "bench" / part / f"{like}.json").read_text())
+    b["end_to_end"][1]["workloads"].append("toy-fit")
+    b["end_to_end"][2]["workloads"].append("toy-predict")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
+
+    for cell in ("toy-fit", "toy-predict"):
+        line = run_small(cell, root=tmp_path)
+        assert line["correct"], line["checks"]
+        assert line["failed"] == 0 and line["attempted"] > 0
+    fit = small_cell("toy-fit", root=tmp_path)
+    assert fit.reference.__file__ == str(tmp_path / "bench/reference/toy.py")
+    assert list(fit.reference.leaves(fit.reference.init_weights(
+        fit.config, 4, 1)))[0] == "toy.hw.alpha_logit"
+    after = _digests(tmp_path / "bench")
+    assert {p: after.get(p) for p in before} == before
+    assert all(b[k][:len(v)] == v for k, v in old.items())
+    # the drivers are shared: none imports a family's module by name
+    for driver in (ROOT / "bench" / "drivers").glob("*.py"):
+        imports = [ln for ln in driver.read_text().splitlines()
+                   if ln.lstrip().startswith(("import ", "from "))]
+        assert not [ln for ln in imports if re.search(
+            r"reference|adapters|weights|flops", ln)], driver.name
+
+
+@pytest.mark.parametrize("family", [None, "no_such_family"])
+def test_a_configuration_without_its_family_fails_to_load(tmp_path, family):
+    _copy_bench(tmp_path)
+    path = tmp_path / "bench/configs/esrnn-monthly.json"
+    cfg = json.loads(path.read_text())
+    del cfg["family"]
+    if family:
+        cfg["family"] = family
+    path.write_text(json.dumps(cfg))
+    missing = ("bench/configs/esrnn-monthly.json" if family is None
+               else f"bench/reference/{family}.py")
+    with pytest.raises(harness.UnknownFamily, match=missing):
+        harness.load_cell(tmp_path, "monthly-predict", seed=1, seconds=1.0,
+                          trace=False)
 
 
 def test_small_predict_run_is_correct():
@@ -146,10 +244,7 @@ def test_no_tpu_exits_nonzero_without_a_result():
 
 
 def test_bench_files_alone_exit_nonzero_without_a_result(tmp_path):
-    shutil.copytree(ROOT / "bench", tmp_path / "bench",
-                    ignore=shutil.ignore_patterns(".jax_cache", ".traces",
-                                                  "__pycache__"))
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    _copy_bench(tmp_path)
     proc = _run(tmp_path, {"PYTHONPATH": ""})
     assert proc.returncode != 0
     assert not _has_result(proc.stdout)
